@@ -3,8 +3,8 @@
 Four measurement surfaces for the kernel pass:
 
   * `kernels/hindex/*` — the h-index kernel variants at a (N, Cd) grid:
-    the O(Cd log Cd) in-tile sort sweep vs the legacy O(Cd*K) count-matrix
-    kernel (K = Cd), plus the single-superstep latency of each registry
+    the O(Cd log Cd) per-row binary search vs the legacy O(Cd*K)
+    threshold-count kernel (K = Cd), plus the single-superstep latency of each registry
     backend.  Off-TPU the Pallas rows run in interpret mode — relative
     variant cost, not hardware speed; parity vs `ref.ell_hindex_ref` is
     asserted on every row (this file is part of the --smoke gate).
@@ -66,28 +66,23 @@ def run(seed: int = 0, smoke: bool = False) -> List[Tuple[str, float, str]]:
     rows = []
     reps = 3 if smoke else 10
 
-    # ---- kernel-variant sweep: sort vs count h-index ------------------
+    # ---- h-index kernel: full columns vs the degree-bucketed bound ------
     shapes = [(512, 256)] if smoke else [(512, 256), (2048, 256), (2048, 512)]
     for N, Cd in shapes:
         g = build_ell_random(N, Cd=Cd, seed=seed, m_factor=Cd / 3)
         est = jnp.asarray(g.deg, jnp.int32)
         want = np.asarray(ref.ell_hindex_ref(g.nbr, est))
         K = ops.degree_bound(g)
-        us_by = {}
-        for variant in ("sort", "count"):
-            got = ops.hindex_ell(g.nbr, est, variant=variant)
-            np.testing.assert_array_equal(np.asarray(got), want)
-            us_by[variant] = _timed(
-                lambda v=variant: ops.hindex_ell(g.nbr, est, variant=v), reps)
-        for variant, us in us_by.items():
-            rows.append(row(
-                f"kernels/hindex/N{g.N}/Cd{Cd}/{variant}", us,
-                f"K={K};sort_speedup={us_by['count'] / max(us_by['sort'], 1e-9):.1f}x"))
+        got = ops.hindex_ell(g.nbr, est)
+        np.testing.assert_array_equal(np.asarray(got), want)
+        rows.append(row(
+            f"kernels/hindex/N{g.N}/Cd{Cd}/bisect",
+            _timed(lambda: ops.hindex_ell(g.nbr, est), reps), f"K={K}"))
         # degree-bucketed K: same kernel, fewer columns swept
         got = ops.hindex_ell(g.nbr, est, K=K)
         np.testing.assert_array_equal(np.asarray(got), want)
         rows.append(row(
-            f"kernels/hindex/N{g.N}/Cd{Cd}/sort_degK",
+            f"kernels/hindex/N{g.N}/Cd{Cd}/bisect_degK",
             _timed(lambda: ops.hindex_ell(g.nbr, est, K=K), reps),
             f"K={K}"))
 

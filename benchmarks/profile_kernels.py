@@ -8,17 +8,18 @@ measured wall-clock time, and emits one roofline point per kernel:
   intensity          = flops / bytes            [ops per byte]
   roofline_bound_us  = max(flops/peak_flops, bytes/peak_bw)
   achieved_fraction  = roofline_bound_us / measured_us   (1.0 = on the
-                       roofline; off-TPU interpret-mode fractions are
-                       tiny and only the RELATIVE ordering is meaningful)
+                       roofline)
 
 The points land in ``PROFILE_kernels.json`` next to the BENCH_*.json
 trajectory files (the distinct prefix keeps ``check_regression``'s
-``BENCH_*`` glob away from them — profile points carry platform peaks,
+``BENCH_*`` glob away from them — profile points carry device peaks,
 not comparable row timings) and ride the same CI artifact upload.
 
-Peaks: TPU v5e per chip (197 TFLOP/s bf16, 819 GB/s HBM) when on TPU;
-a nominal 50 GFLOP/s / 25 GB/s single-stream envelope on CPU hosts,
-where the numbers locate kernels on the roofline qualitatively.
+Peaks come from `PEAKS`, keyed by the device's `device_kind`; a device
+the table does not know (the CPU included) is an error, so a roofline
+share is only ever computed against a chip's published peaks.  The
+triangle kernel has no compiled TPU lowering (see `ell_triangles.py`)
+and is not profiled.
 """
 from __future__ import annotations
 
@@ -32,11 +33,21 @@ import jax.numpy as jnp
 from repro.core import build_ell_random
 from repro.kernels import ops
 
-#: (peak_flops/s, peak_bytes/s) per jax platform
+#: (peak_flops/s, peak_bytes/s) per `device_kind`.  TPU v5e: Google Cloud
+#: documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM per chip.
 PEAKS = {
-    "tpu": (197e12, 819e9),
-    "cpu": (50e9, 25e9),
+    "TPU v5 lite": (197e12, 819e9),
 }
+
+
+def device_peaks(device) -> tuple:
+    """(peak_flops/s, peak_bytes/s) of `device`; raises for unknown kinds."""
+    kind = device.device_kind
+    if kind not in PEAKS:
+        raise ValueError(
+            f"no published peaks for device_kind {kind!r}; known: "
+            f"{sorted(PEAKS)}")
+    return PEAKS[kind]
 
 
 def _timed_us(fn, reps: int = 3) -> float:
@@ -65,8 +76,8 @@ def kernel_models(N: int, Cd: int) -> List[Dict]:
     vec_bytes = Np * 4                 # one (N,) field or output
     gather_bytes = Np * C * 4          # one (N, C) gathered value matrix
     return [
-        dict(name=f"hindex_sort/N{N}/Cd{Cd}",
-             flops=Np * C * (lg + 1),          # bitonic compares + rank test
+        dict(name=f"hindex_bisect/N{N}/Cd{Cd}",
+             flops=Np * C * 2 * (lg + 1),      # compare + count per round
              bytes=nbr_bytes + gather_bytes + vec_bytes * 2),
         dict(name=f"cc_min/N{N}/Cd{Cd}",
              flops=Np * C,                     # row min
@@ -75,37 +86,28 @@ def kernel_models(N: int, Cd: int) -> List[Dict]:
              flops=Np * C,                     # row sum
              bytes=nbr_bytes + gather_bytes + vec_bytes * 2),
         dict(name=f"multi_fused/N{N}/Cd{Cd}",
-             flops=Np * C * (lg + 3),          # shared mask + 3 reduces
+             flops=Np * C * (2 * lg + 4),      # bisect + min + sum
              bytes=nbr_bytes + 3 * (gather_bytes + vec_bytes * 2)),
-        dict(name=f"triangles_merge/N{N}/Cd{Cd}",
-             flops=Np * C * C * 2 * lg,        # dual bisect per (slot, elem)
-             bytes=nbr_bytes * 2 + Np * C * C * 4),  # per-slot row gathers
-        dict(name=f"triangles_allpairs/N{N}/Cd{Cd}",
-             flops=Np * C * C * C,             # all-pairs id compares
-             bytes=nbr_bytes * 2 + Np * C * C * 4),
     ]
 
 
 def profile_points(seed: int = 0, N: int = 320, Cd: int = 24,
                    reps: int = 3) -> Dict:
     """Measure every modeled kernel once and attach roofline terms."""
-    platform = jax.devices()[0].platform
-    peak_f, peak_b = PEAKS.get(platform, PEAKS["cpu"])
+    device = jax.devices()[0]
+    platform = device.platform
+    peak_f, peak_b = device_peaks(device)
     g = build_ell_random(N, Cd=Cd, seed=seed, m_factor=Cd / 3)
     est = jnp.asarray(g.deg, jnp.int32)
     lab = jnp.arange(g.N, dtype=jnp.int32)
     contrib = jnp.where(g.deg > 0, 1.0 / jnp.maximum(g.deg, 1),
                         0.0).astype(jnp.float32)
     dispatch = {
-        "hindex_sort": lambda: ops.hindex_ell(g.nbr, est),
+        "hindex_bisect": lambda: ops.hindex_ell(g.nbr, est),
         "cc_min": lambda: ops.neighbor_min_ell(g.nbr, lab),
         "pagerank_sum": lambda: ops.neighbor_sum_ell(g.nbr, contrib),
         "multi_fused": lambda: ops.neighbor_multi_ell(
             g.nbr, (est, lab, contrib), ("hindex", "min", "sum")),
-        "triangles_merge": lambda: ops.neighbor_common_ell(
-            g.nbr, g.nbr, variant="merge"),
-        "triangles_allpairs": lambda: ops.neighbor_common_ell(
-            g.nbr, g.nbr, variant="allpairs"),
     }
     points = []
     for model in kernel_models(g.N, g.Cd):
@@ -125,6 +127,7 @@ def profile_points(seed: int = 0, N: int = 320, Cd: int = 24,
         "profile": "kernels",
         "platform": {
             "jax_backend": platform,
+            "device_kind": device.device_kind,
             "device_count": len(jax.devices()),
         },
         "peaks": {"flops_per_s": peak_f, "bytes_per_s": peak_b},

@@ -9,7 +9,7 @@ Prints ``name,us_per_call,derived`` CSV rows.
   kcore_static — static decomposition time + supersteps        (§4.1 step 1)
   backends — jnp vs dense vs ELL registry sweep incl. the >4 GiB dense-
              infeasible N (EXPERIMENTS.md §Backends)
-  kernels  — h-index kernel variants (sort vs count) + fused-vs-host-loop
+  kernels  — h-index kernel variants (bisect vs count) + fused-vs-host-loop
              fixpoint latency (EXPERIMENTS.md §Kernels)
   runtime  — mesh (ell_spmd) coreness parity/time + metered vs executed
              W2W accounting (EXPERIMENTS.md §Runtime)
@@ -104,6 +104,10 @@ def main() -> None:
     ap.add_argument("--out-dir", default=".",
                     help="directory for the BENCH_*.json trajectory files")
     args = ap.parse_args()
+
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from . import (bench_backends, bench_elastic, bench_kcore_maintenance,
                    bench_kernels, bench_vs_naive_kcore, bench_partitioning,

@@ -70,8 +70,7 @@ if args.stream:
     from repro.runtime import run_stream
 
     t0 = time.time()
-    res = run_stream(g, core, ups, R=8, backend=args.backend
-                     if args.backend != "auto" else "jnp")
+    res = run_stream(g, core, ups, R=8, backend=args.backend)
     g, core, st = res.g, res.core, res.stats
     jax.block_until_ready(core)
     dt = time.time() - t0

@@ -204,12 +204,13 @@ def build_blocks(
     N = P * Cn
     nbr = np.full((N, Cd), PAD, dtype=np.int64)
     fill = np.zeros(N, dtype=np.int64)
-    for a, b in edges:
-        na, nb_ = new_of_old[a], new_of_old[b]
-        nbr[na, fill[na]] = nb_
-        fill[na] += 1
-        nbr[nb_, fill[nb_]] = na
-        fill[nb_] += 1
+    if edges.size:
+        # both directions of every edge; each row takes its entries in
+        # occurrence order, and the sort below fixes the slot order
+        lo, hi = new_of_old[edges[:, 0]], new_of_old[edges[:, 1]]
+        src, dst = np.concatenate([lo, hi]), np.concatenate([hi, lo])
+        nbr[src, _occurrence_ranks(src)] = dst
+        fill = np.bincount(src, minlength=N).astype(np.int64)
     nbr = sort_nbr_rows(nbr)  # establish the sorted-ELL invariant
     node_mask = old_of_new >= 0
 

@@ -24,7 +24,7 @@ analysis in PAPERS.md) *without* changing the block-centric runtime:
     **combine-then-broadcast merge** between the neighbor combine and
     `BlockProgram.update`: per-slice partial aggregates are merged per
     group (min/sum exactly associative; hindex via count-histogram
-    partials, the ``variant="count"`` formulation) and the merged value
+    partials, the threshold-count formulation) and the merged value
     is written back to every group row.  Because program state is
     replicated onto mirror rows (`BlockProgram.mirror_state`), replicas
     advance in lockstep with their primary and every *reader* of a

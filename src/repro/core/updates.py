@@ -78,9 +78,9 @@ def sample_deletions(
     """Sample `count` existing edges to delete, by scenario."""
     rng = np.random.default_rng(seed)
     nbr_np = np.asarray(g.nbr)
-    src = np.repeat(np.arange(g.N), g.Cd)
-    dst = nbr_np.reshape(-1)
-    ok = (dst >= 0) & (src < dst)
+    src, col = np.nonzero(nbr_np >= 0)  # row-major: valid slots only
+    dst = nbr_np[src, col]
+    ok = src < dst
     src, dst = src[ok], dst[ok]
     same = (src // g.Cn) == (dst // g.Cn)
     pick = same if scenario == "intra" else ~same

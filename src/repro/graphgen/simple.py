@@ -93,12 +93,13 @@ def grid_like(n: int, seed: int = 0, diag_frac: float = 0.05) -> np.ndarray:
     ok = (xs + 1 < side).reshape(-1) & keep & (down < n)
     edges.append(np.stack([idx[ok], down[ok]], 1))
     e = np.concatenate(edges)
-    # sparse random diagonals
+    # sparse random diagonals; one that would leave the lattice is dropped
+    # (clipping it to the last node would pile them onto one hub)
     extra = int(diag_frac * len(e))
     if extra:
         a = rng.integers(0, n, size=extra)
-        b = np.clip(a + side + 1, 0, n - 1)
-        ok = a != b
+        b = a + side + 1
+        ok = b < n
         e = np.concatenate([e, np.stack([a[ok], b[ok]], 1)])
     lo = np.minimum(e[:, 0], e[:, 1])
     hi = np.maximum(e[:, 0], e[:, 1])

@@ -5,8 +5,10 @@ block-sparse (O(N*Cd), consumes `GraphBlocks.nbr` tiles directly) — plus the
 pure-jnp oracles in `ref.py`.  Core code selects between them only through
 `ops` (`backend="auto"|"jnp"|"dense"|"ell"`).
 
-Validated in interpret mode against the oracles; TPU is the compile target
-(explicit BlockSpec VMEM tiling, MXU-aligned).
+Validated in interpret mode against the oracles on the CPU, and compiled
+for TPU v5e at the full roadNet-CA widths by `tests/test_tpu_compile.py`
+(explicit BlockSpec VMEM tiling, MXU-aligned); `ell_triangles` has no TPU
+lowering.
 """
 from . import ops, ref
 from .kcore_hindex import hindex_counts

@@ -5,32 +5,18 @@ The connected-components hot loop is the "min" neighbor combine of the
 current component labels and keeps the minimum.  Structurally this is the
 h-index kernel (`ell_hindex.py`) with the row reduction swapped — gather
 through the ELL neighbor lists, reduce each row — so it shares the same
-tiling:
+tiling and the same pre-kernel XLA gather (`ell_hindex.ell_row_call`):
 
     nbr[N, Cd]   int32   padded neighbor ids (-1 = empty slot)
     field[N]     int32   current labels (component = min member id)
 
-Per row tile of T nodes (grid axis i), a chunked, double-buffered sweep
-over the neighbor slots:
-  1. trip bound  the sweep **early-exits** at the highest occupied column
-                 of the tile — the sorted-ELL invariant (`core.graph`)
-                 keeps pads on the right, so column occupancy is monotone
-                 and `ceil(maxcol / chunk)` trips cover every valid slot;
-  2. gather      each trip pulls a (T, chunk) slot slice and gathers
-                 `field[idx]` (PAD slots -> int32 max, the min-combine's
-                 absorbing fill) — the *next* trip's gather is issued
-                 before the current trip's reduce consumes its values
-                 (software double-buffering: on TPU the DMA for trip j+1
-                 overlaps the VPU reduce of trip j);
-  3. reduce      out[t] = min over trips and chunk slots.
-
-Rows with no valid slots reduce to int32 max — `BlockProgram.update`
-takes `min(own, red)`, so the fill is harmless by construction.  A
-max-degree column bound K < Cd (left-filled rows, `ops.degree_bound`)
-restricts the sweep like the sibling kernels.  O(N*Cd) memory; the full
-label vector rides in VMEM as a (1, N) int32 row, like the estimate
-vector of `ell_hindex.py`.  Validated in interpret mode against
-`ref.ell_min_ref`.
+Per row tile of T nodes (grid axis i) the kernel reads the (T, C) tile of
+gathered labels (PAD slots -> int32 max, the min-combine's absorbing fill)
+and writes out[t] = min over the row.  Rows with no valid slots reduce to
+int32 max — `BlockProgram.update` takes `min(own, red)`, so the fill is
+harmless by construction.  A max-degree column bound K < Cd (left-filled
+rows, `ops.degree_bound`) restricts the gather like the sibling kernels.
+Bit-identical to `ref.ell_min_ref`.
 """
 from __future__ import annotations
 
@@ -38,50 +24,24 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from ._compat import CompilerParams as _CompilerParams
+
+from .ell_hindex import check_cols, ell_row_call
 
 #: absorbing fill for the min combine (what PAD slots read as)
 MIN_FILL = jnp.iinfo(jnp.int32).max
 
-#: neighbor slots gathered per trip (divides 128, so any padded column
-#: count is a multiple of it)
-CHUNK = 8
+
+def _ell_min_kernel(vals_ref, out_ref):
+    out_ref[...] = jnp.min(vals_ref[...], axis=1, keepdims=True)
 
 
-def _ell_min_kernel(nbr_ref, field_ref, out_ref, *, C: int, T: int, chunk: int):
-    nbr = nbr_ref[...]  # (T, C) int32, -1 padded
-    field = field_ref[0]
-
-    def gather(j):  # values of slot chunk j, PAD -> absorbing fill
-        idx = jax.lax.dynamic_slice(nbr, (0, j * chunk), (T, chunk))
-        vals = jnp.take(field, jnp.clip(idx, 0).reshape(-1), axis=0)
-        return jnp.where(idx >= 0, vals.reshape(T, chunk), MIN_FILL)
-
-    def body(j, carry):
-        acc, cur = carry
-        nxt = gather(j + 1)  # prefetch j+1 before reducing j (double buffer)
-        return jnp.minimum(acc, jnp.min(cur, axis=1)), nxt
-
-    # early exit: pad-right rows ⇒ columns past the highest occupied one
-    # are all PAD, so ceil(maxcol/chunk) trips suffice
-    cols_any = jnp.any(nbr >= 0, axis=0)
-    maxcol = jnp.max(jnp.where(cols_any, jnp.arange(C, dtype=jnp.int32) + 1, 0))
-    trips = (maxcol + chunk - 1) // chunk
-
-    acc0 = jnp.full((T,), MIN_FILL, jnp.int32)
-    acc, _ = jax.lax.fori_loop(0, trips, body, (acc0, gather(0)))
-    out_ref[...] = acc[:, None]
-
-
-@functools.partial(jax.jit, static_argnames=("K", "T", "interpret", "chunk"))
+@functools.partial(jax.jit, static_argnames=("K", "T", "interpret"))
 def neighbor_min_ell(
     nbr: jax.Array,
     field: jax.Array,
     K: int,
     T: int = 256,
-    interpret: bool = True,
-    chunk: int = CHUNK,
+    interpret: bool = False,
 ) -> jax.Array:
     """Row-wise min of neighbor field values over the ELL adjacency.
 
@@ -89,28 +49,14 @@ def neighbor_min_ell(
     exact iff every row's valid slots lie in the first K columns (always
     true for K >= Cd; K < Cd needs left-filled rows, the `GraphBlocks`
     invariant).  Returns (N,) int32 with int32-max on neighborless rows.
-    N % T == 0 and Cd, K multiples of 128 (pad via the ops.py wrapper).
+    N % T == 0; Cd and K pass `check_cols` (pad via the ops.py wrapper).
     """
     N, Cd = nbr.shape
     assert field.shape == (N,), (field.shape, N)
     assert N % T == 0, (N, T)
-    assert Cd % 128 == 0 and K % 128 == 0, (Cd, K)
+    check_cols(Cd, K)
     C = min(Cd, K)
-    assert C % chunk == 0, (C, chunk)
-    ni = N // T
-
-    out = pl.pallas_call(
-        functools.partial(_ell_min_kernel, C=C, T=T, chunk=chunk),
-        grid=(ni,),
-        in_specs=[
-            pl.BlockSpec((T, C), lambda i: (i, 0)),  # neighbor-list row tile
-            pl.BlockSpec((1, N), lambda i: (0, 0)),   # full label vector
-        ],
-        out_specs=pl.BlockSpec((T, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, 1), jnp.int32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)
-        ),
-        interpret=interpret,
-    )(nbr[:, :C], field.astype(jnp.int32)[None, :])
-    return out[:, 0]
+    (red,) = ell_row_call(_ell_min_kernel, nbr[:, :C],
+                          (field.astype(jnp.int32),), (MIN_FILL,),
+                          (jnp.int32,), T, interpret)
+    return red

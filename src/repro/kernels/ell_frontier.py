@@ -8,26 +8,22 @@ maintenance axis of `core.kcore_dynamic.maintain_batch`):
     next[u, r] = (exists j: f[nbr[u, j], r]) & eligible[u, r] & ~visited[u, r]
 
 For undirected ELL storage (every edge stored in both endpoint rows) the
-gather formulation above equals the scatter-or over outgoing slots, so one
-row tile of `nbr` plus the full frontier matrix in VMEM suffices.  Unlike the
-dense kernel, `eligible` here carries a per-frontier column axis — batched
-maintenance stacks updates with *different* k values, so each column has its
-own k-level eligibility mask.
+gather formulation above equals the scatter-or over outgoing slots.  Unlike
+the dense kernel, `eligible` here carries a per-frontier column axis —
+batched maintenance stacks updates with *different* k values, so each
+column has its own k-level eligibility mask.
 
-Grid: row tiles i; per tile a `fori_loop` over chunks of `chunk` neighbor
-slots gathers `T*chunk` frontier rows at once (`jnp.take`, see the lowering
-note in ell_hindex.py) and ORs the chunk-reduced (T, R) hit mask into a
-register accumulator — Cd/chunk gather launches instead of Cd single-slot
-gathers, amortizing the per-gather latency.  The sweep **early-exits** at
-the highest occupied column of the tile (the sorted-ELL invariant of
-`core.graph` keeps pads on the right, so column occupancy is monotone),
-and is **double-buffered**: the gather for chunk j+1 is issued before the
-reduce of chunk j consumes its rows, so on TPU the next DMA overlaps the
-current VPU reduction.  Like the h-index kernel, a max-degree column bound
-K < Cd (left-filled rows, see `ops.degree_bound`) restricts the sweep to
-the first K slots.  The eligibility/visited epilogue is fused (no HBM
-round-trip).  Validated in interpret mode against
-`ref.ell_frontier_hop_ref`.
+Bit-packed frontiers: the R columns pack into ceil(R / 32) int32 words per
+node (bit r % 32 of word r // 32), so the pre-kernel XLA gather
+(`ell_hindex.ell_row_call`, PAD slots -> 0) moves one (N, C) int32 matrix
+per word — the size of the adjacency itself — instead of an (N, C, R)
+frontier block.  Per row tile of T nodes (grid axis i) the kernel ORs the
+(T, C) word tile across its row (128-lane chunks, then a log2(128)-step
+lane rotation), and fuses the eligibility/visited epilogue as bitwise ops
+on the packed words: every operation is int32, which every TPU generation
+lowers.  A max-degree column bound K < Cd (left-filled rows, see
+`ops.degree_bound`) restricts the gather to the first K slots.
+Bit-identical to `ref.ell_frontier_hop_ref`.
 """
 from __future__ import annotations
 
@@ -36,44 +32,46 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from ._compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
-#: neighbor slots gathered per loop iteration (divides 128, so any padded
-#: column count is a multiple of it)
-CHUNK = 8
+from .ell_hindex import ell_row_call
 
+#: frontier columns per packed int32 word
+WORD = 32
 
-def _ell_frontier_kernel(
-    nbr_ref, f_ref, elig_ref, vis_ref, out_ref, *, C: int, T: int, chunk: int
-):
-    nbr = nbr_ref[...]  # (T, C) int32, -1 padded
-    f_full = f_ref[...]  # (N, R) int8
-    R = f_full.shape[1]
-
-    def gather(j):  # slot ids + frontier rows of chunk j
-        idx = jax.lax.dynamic_slice(nbr, (0, j * chunk), (T, chunk))  # (T, c)
-        rows = jnp.take(f_full, jnp.clip(idx, 0).reshape(-1), axis=0)
-        return idx, rows.reshape(T, chunk, R)  # (T, c, R)
-
-    def body(j, carry):
-        acc, (idx, rows) = carry
-        nxt = gather(j + 1)  # prefetch j+1 before reducing j (double buffer)
-        hit = jnp.any((rows > 0) & (idx >= 0)[:, :, None], axis=1)  # (T, R)
-        return acc | hit, nxt
-
-    # early exit: pad-right rows ⇒ ceil(maxcol/chunk) trips cover all slots
-    cols_any = jnp.any(nbr >= 0, axis=0)
-    maxcol = jnp.max(jnp.where(cols_any, jnp.arange(C, dtype=jnp.int32) + 1, 0))
-    trips = (maxcol + chunk - 1) // chunk
-
-    hit, _ = jax.lax.fori_loop(
-        0, trips, body, (jnp.zeros((T, R), jnp.bool_), gather(0)))
-    out_ref[...] = (
-        hit & (elig_ref[...] > 0) & ~(vis_ref[...] > 0)
-    ).astype(jnp.int8)
+_LANES = 128
 
 
-@functools.partial(jax.jit, static_argnames=("K", "T", "interpret", "chunk"))
+def pack_words(x: jax.Array) -> jax.Array:
+    """(N, R) 0/1 -> (N, ceil(R/32)) int32 bit words (bit r%32 of word r//32)."""
+    N, R = x.shape
+    nw = -(-R // WORD)
+    bits = jnp.zeros((N, nw * WORD), jnp.int32).at[:, :R].set(
+        x.astype(jnp.int32))
+    bits = bits.reshape(N, nw, WORD) << jnp.arange(WORD, dtype=jnp.int32)
+    return jax.lax.reduce(bits, jnp.int32(0), jax.lax.bitwise_or, (2,))
+
+
+def unpack_words(w: jax.Array, R: int) -> jax.Array:
+    """Inverse of `pack_words`: (N, nw) int32 -> (N, R) bool."""
+    bits = (w[:, :, None] >> jnp.arange(WORD, dtype=jnp.int32)) & 1
+    return bits.reshape(w.shape[0], -1)[:, :R] > 0
+
+
+def _ell_frontier_kernel(vals_ref, elig_ref, vis_ref, out_ref, *, C: int):
+    # OR across the row: fold the 128-lane chunks, then rotate-and-OR the
+    # lanes (after log2(128) steps every lane holds the whole row's OR)
+    def fold(c, acc):
+        lanes = pl.ds(pl.multiple_of(c * _LANES, _LANES), _LANES)
+        return acc | vals_ref[:, lanes]
+
+    acc = jax.lax.fori_loop(1, C // _LANES, fold, vals_ref[:, 0:_LANES])
+    for shift in (64, 32, 16, 8, 4, 2, 1):  # log2(_LANES) rotations
+        acc = acc | pltpu.roll(acc, shift, 1)
+    out_ref[...] = acc[:, 0:1] & elig_ref[...] & ~vis_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=("K", "T", "interpret"))
 def frontier_step_ell(
     nbr: jax.Array,
     f: jax.Array,
@@ -81,46 +79,27 @@ def frontier_step_ell(
     visited: jax.Array,
     K: int,
     T: int = 256,
-    interpret: bool = True,
-    chunk: int = CHUNK,
+    interpret: bool = False,
 ) -> jax.Array:
     """One masked BFS hop for R stacked frontiers over the ELL adjacency.
 
-    nbr: (N, Cd) int32 (-1 padded); f: (N, R) 0/1; eligible: (N, R) 0/1 int8
-    (per-column k-level masks); visited: (N, R) 0/1 int8.  K is the column
-    bound: exact iff valid slots lie in the first K columns (K >= Cd always
-    works; K < Cd needs left-filled rows — the `GraphBlocks` invariant).
-    Returns the next frontier (N, R) int8.  N % T == 0, Cd % 128 == 0,
-    K % 128 == 0, R % 128 == 0 (pad via the ops.py wrapper).
+    nbr: (N, Cd) int32 (-1 padded); f, eligible, visited: (N, R) 0/1
+    (per-column k-level eligibility masks).  K is the column bound: exact
+    iff valid slots lie in the first K columns (K >= Cd always works;
+    K < Cd needs left-filled rows — the `GraphBlocks` invariant).  Returns
+    the next frontier (N, R) bool.  N % T == 0 and Cd, K multiples of 128
+    (pad via the ops.py wrapper); R is free.
     """
     N, Cd = nbr.shape
     R = f.shape[1]
     assert f.shape == (N, R) and visited.shape == (N, R), (f.shape, visited.shape)
     assert eligible.shape == (N, R), eligible.shape
-    assert N % T == 0 and Cd % 128 == 0 and R % 128 == 0, (N, T, Cd, R)
-    assert K % 128 == 0, K
+    assert N % T == 0 and Cd % 128 == 0 and K % 128 == 0, (N, T, Cd, K)
     C = min(Cd, K)
-    assert C % chunk == 0, (C, chunk)
-    ni = N // T
-
-    kernel = functools.partial(_ell_frontier_kernel, C=C, T=T, chunk=chunk)
-    out = pl.pallas_call(
-        kernel,
-        grid=(ni,),
-        in_specs=[
-            pl.BlockSpec((T, C), lambda i: (i, 0)),  # neighbor-list row tile
-            pl.BlockSpec((N, R), lambda i: (0, 0)),   # full frontier matrix
-            pl.BlockSpec((T, R), lambda i: (i, 0)),   # eligibility tile
-            pl.BlockSpec((T, R), lambda i: (i, 0)),   # visited tile
-        ],
-        out_specs=pl.BlockSpec((T, R), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, R), jnp.int8),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)
-        ),
-        interpret=interpret,
-    )(
-        nbr[:, :C], f.astype(jnp.int8), eligible.astype(jnp.int8),
-        visited.astype(jnp.int8),
-    )
-    return out
+    fw, ew, vw = pack_words(f), pack_words(eligible), pack_words(visited)
+    kernel = functools.partial(_ell_frontier_kernel, C=C)
+    words = [
+        ell_row_call(kernel, nbr[:, :C], (fw[:, w],), (0,), (jnp.int32,), T,
+                     interpret, row_args=(ew[:, w:w + 1], vw[:, w:w + 1]))[0]
+        for w in range(fw.shape[1])]
+    return unpack_words(jnp.stack(words, axis=1), R)

@@ -1,28 +1,24 @@
-"""Pallas TPU kernel: fused multi-field ELL superstep (one adjacency read).
+"""Pallas TPU kernel: fused multi-field ELL superstep (one launch, k fields).
 
 A `MultiProgram` (`core.engine`) advances several `BlockProgram`s in
 lockstep — e.g. coreness + CC labels + PageRank.  Run separately, every
-sub-program's superstep re-reads the same (N, Cd) ELL adjacency to gather
-its own field: three programs, three sweeps of the largest operand on the
-roofline.  This kernel fuses the sweep: ONE pallas launch per tile reads
-the neighbor-id tile once, computes the slot validity mask and clipped
-gather indices once, and then serves every field's gather + named reduce
-off that shared index matrix — k fields cost one adjacency read plus k
-cheap (N,)-vector reads instead of k full sweeps.
+sub-program's superstep is its own gather + kernel launch over the same
+(N, Cd) ELL adjacency.  Fused, the slot validity mask and clipped gather
+indices are computed once, every field's gather reads them (one XLA
+fusion, `ell_hindex.ell_row_call`), and ONE pallas launch per
+row tile reduces all k value tiles.
 
 Supported per-field combines (`MULTI_COMBINES` in ops.py): "min" (CC
 label propagation, int32), "sum" (PageRank push, float32), "hindex"
 (min-H coreness, int32).  "count_common" is excluded — its field is the
 (N, Cd) row matrix, which would defeat the shared-gather point.  Each
-reduce reproduces the standalone kernel's formulation operation-for-
-operation (same gather, same fill, same reduction axis/order), so fused
-results are bit-identical to the dedicated `ell_cc` / `ell_pagerank` /
-`ell_hindex` launches.
+reduce is the standalone kernel's formulation (same fill, same reduction
+axis/order; hindex by `ell_hindex.hindex_bisect`), so fused results are
+bit-identical to the dedicated `ell_cc` / `ell_pagerank` / `ell_hindex`
+launches.
 
-Tiling is the family standard: row tiles of T nodes on grid axis i, each
-field riding in VMEM as a (1, N) row, a max-degree column bound K < Cd
-honored on left-filled rows.  Validated in interpret mode against the
-`ref.py` oracles per field.
+Tiling is the family standard: row tiles of T nodes on grid axis i, a
+max-degree column bound K < Cd honored on left-filled rows.
 """
 from __future__ import annotations
 
@@ -31,9 +27,9 @@ from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from ._compat import CompilerParams as _CompilerParams
+
 from .ell_cc import MIN_FILL
+from .ell_hindex import check_cols, ell_row_call, hindex_bisect
 
 #: combines the fused kernel can serve, with their (dtype, pad fill)
 _FIELD_SPEC = {
@@ -44,27 +40,18 @@ _FIELD_SPEC = {
 
 
 def _reduce_one(combine: str, vals: jax.Array) -> jax.Array:
-    """The standalone kernels' row reductions, shared-gather edition."""
+    """The standalone kernels' row reductions: (T, C) -> (T, 1)."""
     if combine == "min":
         return jnp.min(vals, axis=1, keepdims=True)
     if combine == "sum":
         return jnp.sum(vals, axis=1, keepdims=True)
-    # hindex: descending in-tile sort + prefix-monotone position compare
-    s = -jnp.sort(-vals, axis=1)
-    ranks = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + 1
-    return jnp.sum((s >= ranks).astype(jnp.int32), axis=1, keepdims=True)
+    return hindex_bisect(vals)
 
 
-def _ell_multi_kernel(nbr_ref, *refs, combines: Tuple[str, ...], T: int):
+def _ell_multi_kernel(*refs, combines: Tuple[str, ...]):
     n = len(combines)
-    field_refs, out_refs = refs[:n], refs[n:]
-    nbr = nbr_ref[...]          # (T, C) int32, -1 padded — read ONCE
-    valid = nbr >= 0            # shared slot validity
-    idx = jnp.clip(nbr, 0)      # shared gather indices
-    for combine, f_ref, o_ref in zip(combines, field_refs, out_refs):
-        _, fill = _FIELD_SPEC[combine]
-        vals = jnp.where(valid, jnp.take(f_ref[0], idx, axis=0), fill)
-        o_ref[...] = _reduce_one(combine, vals)
+    for combine, v_ref, o_ref in zip(combines, refs[:n], refs[n:]):
+        o_ref[...] = _reduce_one(combine, v_ref[...])
 
 
 @functools.partial(
@@ -75,15 +62,15 @@ def neighbor_multi_ell(
     combines: Tuple[str, ...],
     K: int,
     T: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, ...]:
-    """Fused multi-field neighbor reduce over ONE ELL adjacency read.
+    """Fused multi-field neighbor reduce over one shared ELL gather.
 
     nbr: (N, Cd) int32 (-1 padded); fields: one (N,) vector per combine
     (int32 for "min"/"hindex", float32 for "sum"); combines: static tuple
     of names from `_FIELD_SPEC`.  Returns one (N,) reduction per field,
-    each bit-identical to its standalone kernel.  N % T == 0 and Cd, K
-    multiples of 128 (pad via the ops.py wrapper).
+    each bit-identical to its standalone kernel.  N % T == 0; Cd and K
+    pass `check_cols` (pad via the ops.py wrapper).
     """
     N, Cd = nbr.shape
     assert len(fields) == len(combines) >= 1, (len(fields), combines)
@@ -91,24 +78,10 @@ def neighbor_multi_ell(
         assert c in _FIELD_SPEC, c
         assert f.shape == (N,), (c, f.shape, N)
     assert N % T == 0, (N, T)
-    assert Cd % 128 == 0 and K % 128 == 0, (Cd, K)
+    check_cols(Cd, K)
     C = min(Cd, K)
-    ni = N // T
-
-    field_rows = tuple(
-        f.astype(_FIELD_SPEC[c][0])[None, :] for c, f in zip(combines, fields))
-    outs = pl.pallas_call(
-        functools.partial(_ell_multi_kernel, combines=combines, T=T),
-        grid=(ni,),
-        in_specs=[pl.BlockSpec((T, C), lambda i: (i, 0))]   # nbr row tile
-        + [pl.BlockSpec((1, N), lambda i: (0, 0))           # each field row
-           for _ in combines],
-        out_specs=[pl.BlockSpec((T, 1), lambda i: (i, 0)) for _ in combines],
-        out_shape=[
-            jax.ShapeDtypeStruct((N, 1), _FIELD_SPEC[c][0]) for c in combines],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)
-        ),
-        interpret=interpret,
-    )(nbr[:, :C], *field_rows)
-    return tuple(o[:, 0] for o in outs)
+    dtypes = tuple(_FIELD_SPEC[c][0] for c in combines)
+    return ell_row_call(
+        functools.partial(_ell_multi_kernel, combines=combines), nbr[:, :C],
+        tuple(f.astype(d) for f, d in zip(fields, dtypes)),
+        tuple(_FIELD_SPEC[c][1] for c in combines), dtypes, T, interpret)

@@ -3,23 +3,19 @@
 The push-style PageRank superstep is the "sum" neighbor combine of the
 `BlockProgram` contract: every node's exchanged field is its outgoing
 contribution rank/deg, and each superstep sums the contributions of its
-neighbors.  Same ELL tiling as the h-index/min kernels, float32 payload:
+neighbors.  Same ELL tiling and pre-kernel XLA gather as the h-index/min
+kernels (`ell_hindex.ell_row_call`), float32 payload:
 
     nbr[N, Cd]   int32    padded neighbor ids (-1 = empty slot)
     field[N]     float32  per-node contribution (rank[u] / deg[u])
 
-Per row tile of T nodes (grid axis i):
-  1. gather   vals[t, j] = field[nbr[t, j]]     (PAD slots -> 0.0, the
-              sum-combine's absorbing fill)
-  2. reduce   out[t] = sum_j vals[t, j]
-
-The accumulation order within a row is the same axis-1 reduction the jnp
-oracle performs, so cross-backend drift stays at normal float32
-reassociation noise (the parity tests use allclose, not bit equality).
-O(N*Cd) memory; the full contribution vector rides in VMEM as a (1, N)
-float32 row.  A max-degree column bound K < Cd (left-filled rows) is
-honored like the sibling kernels.  Validated in interpret mode against
-`ref.ell_sum_ref`.
+Per row tile of T nodes (grid axis i) the kernel reads the (T, C) tile of
+gathered contributions (PAD slots -> 0.0, the sum-combine's absorbing
+fill) and writes out[t] = sum over the row.  The accumulation order within
+a row is the same axis-1 reduction the jnp oracle performs, so
+cross-backend drift stays at normal float32 reassociation noise (the
+parity tests use allclose, not bit equality).  A max-degree column bound
+K < Cd (left-filled rows) is honored like the sibling kernels.
 """
 from __future__ import annotations
 
@@ -27,18 +23,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from ._compat import CompilerParams as _CompilerParams
+
+from .ell_hindex import check_cols, ell_row_call
 
 
-def _ell_sum_kernel(nbr_ref, field_ref, out_ref, *, T: int):
-    nbr = nbr_ref[...]  # (T, C) int32, -1 padded
-    vals = jnp.where(
-        nbr >= 0,
-        jnp.take(field_ref[0], jnp.clip(nbr, 0), axis=0),
-        jnp.float32(0.0),
-    )
-    out_ref[...] = jnp.sum(vals, axis=1, keepdims=True)
+def _ell_sum_kernel(vals_ref, out_ref):
+    out_ref[...] = jnp.sum(vals_ref[...], axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("K", "T", "interpret"))
@@ -47,34 +37,21 @@ def neighbor_sum_ell(
     field: jax.Array,
     K: int,
     T: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Row-wise sum of neighbor field values over the ELL adjacency.
 
     nbr: (N, Cd) int32 (-1 padded); field: (N,) float32; K: column bound
     (exact for K >= Cd, or K < Cd on left-filled rows).  Returns (N,)
-    float32 with 0.0 on neighborless rows.  N % T == 0 and Cd, K
-    multiples of 128 (pad via the ops.py wrapper).
+    float32 with 0.0 on neighborless rows.  N % T == 0; Cd and K pass
+    `check_cols` (pad via the ops.py wrapper).
     """
     N, Cd = nbr.shape
     assert field.shape == (N,), (field.shape, N)
     assert N % T == 0, (N, T)
-    assert Cd % 128 == 0 and K % 128 == 0, (Cd, K)
+    check_cols(Cd, K)
     C = min(Cd, K)
-    ni = N // T
-
-    out = pl.pallas_call(
-        functools.partial(_ell_sum_kernel, T=T),
-        grid=(ni,),
-        in_specs=[
-            pl.BlockSpec((T, C), lambda i: (i, 0)),  # neighbor-list row tile
-            pl.BlockSpec((1, N), lambda i: (0, 0)),   # full contribution row
-        ],
-        out_specs=pl.BlockSpec((T, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)
-        ),
-        interpret=interpret,
-    )(nbr[:, :C], field.astype(jnp.float32)[None, :])
-    return out[:, 0]
+    (red,) = ell_row_call(_ell_sum_kernel, nbr[:, :C],
+                          (field.astype(jnp.float32),), (0.0,),
+                          (jnp.float32,), T, interpret)
+    return red

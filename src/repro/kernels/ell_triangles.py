@@ -36,6 +36,13 @@ Both variants: O(N * Cd) memory (never densifies), a max-degree column
 bound K < Cd (left-filled rows, `ops.degree_bound`) restricts both the
 swept slots and the compared columns.  Validated in interpret mode
 against `ref.ell_common_ref`.
+
+No compiled TPU lowering: each swept slot gathers whole neighbor rows
+from the (N, C) row matrix inside the kernel, which Mosaic cannot lower
+(only 2-D gathers, no value `dynamic_slice`), and moving that gather out
+of the kernel would materialize N * C^2 ids.  With `interpret=False` the
+entry point raises `NotImplementedError` instead of compiling; the jnp
+and ell_spmd backends serve "count_common" on the chip.
 """
 from __future__ import annotations
 
@@ -44,7 +51,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from ._compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 #: intersection variants: sorted binary-probe merge vs legacy all-pairs
 VARIANTS = ("merge", "allpairs")
@@ -131,7 +138,7 @@ def neighbor_common_ell(
     rows: jax.Array,
     K: int,
     T: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
     variant: str = "merge",
 ) -> jax.Array:
     """Directed common-neighbor counts over the ELL adjacency.
@@ -153,6 +160,11 @@ def neighbor_common_ell(
     assert N % T == 0, (N, T)
     assert Cd % 128 == 0 and K % 128 == 0, (Cd, K)
     assert variant in VARIANTS, variant
+    if not interpret:
+        raise NotImplementedError(
+            "ell_triangles has no compiled TPU lowering (its per-slot "
+            "neighbor-row gather needs the whole (N, Cd) row matrix inside "
+            "the kernel); run count_common on backend='jnp' or 'ell_spmd'")
     C = min(Cd, K)
     ni = N // T
 
@@ -174,7 +186,7 @@ def neighbor_common_ell(
         ],
         out_specs=pl.BlockSpec((T, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, 1), jnp.int32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-
 
 def _frontier_kernel(adj_ref, f_ref, elig_ref, vis_ref, out_ref, acc_ref, *, nj: int):
     j = pl.program_id(1)
@@ -38,7 +36,7 @@ def _frontier_kernel(adj_ref, f_ref, elig_ref, vis_ref, out_ref, acc_ref, *, nj:
         hit = acc_ref[...] > 0.0
         elig = elig_ref[...] > 0  # (T, 1) broadcasts over R
         vis = vis_ref[...] > 0
-        out_ref[...] = (hit & elig & ~vis).astype(jnp.int8)
+        out_ref[...] = (hit & elig & ~vis).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("T", "interpret"))
@@ -48,12 +46,13 @@ def frontier_step(
     eligible: jax.Array,
     visited: jax.Array,
     T: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """One masked BFS hop for R stacked frontiers.
 
-    adj: (N, N) 0/1 bf16/f32; f: (N, R) 0/1; eligible: (N,) 0/1 int8;
-    visited: (N, R) 0/1 int8.  Returns next frontier (N, R) int8.
+    adj: (N, N) 0/1 bf16/f32; f: (N, R) 0/1; eligible: (N,) 0/1;
+    visited: (N, R) 0/1.  Returns next frontier (N, R) int32 0/1 (masks
+    and output are int32: the TPU's vector compare has no int8 form).
     N % T == 0 and R % 128 == 0 (pad via ops.py wrapper).
     """
     N, R = f.shape
@@ -73,11 +72,12 @@ def frontier_step(
             pl.BlockSpec((T, R), lambda i, j: (i, 0)),  # visited
         ],
         out_specs=pl.BlockSpec((T, R), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, R), jnp.int8),
+        out_shape=jax.ShapeDtypeStruct((N, R), jnp.int32),
         scratch_shapes=[pltpu.VMEM((T, R), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(adj, f.astype(adj.dtype), eligible[:, None].astype(jnp.int8), visited)
+    )(adj, f.astype(adj.dtype), eligible[:, None].astype(jnp.int32),
+      visited.astype(jnp.int32))
     return out

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-
 
 def _hindex_kernel(est_ref, adj_ref, out_ref, acc_ref, *, K: int, nj: int, T: int):
     j = pl.program_id(1)
@@ -63,7 +61,7 @@ def hindex_counts(
     est: jax.Array,
     K: int,
     T: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """h-index of every node; dense adjacency path.
 
@@ -88,7 +86,7 @@ def hindex_counts(
         out_specs=pl.BlockSpec((T, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, 1), jnp.int32),
         scratch_shapes=[pltpu.VMEM((T, K), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

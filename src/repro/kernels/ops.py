@@ -97,18 +97,26 @@ MULTI_COMBINES = ("min", "sum", "hindex")
 #: the O(N^2) adjacency dominates memory and ELL wins (see EXPERIMENTS.md).
 DENSE_AUTO_MAX = 4096
 
-#: measured on-TPU crossover for "auto" (see EXPERIMENTS.md §Backends):
-#: below JNP_AUTO_MAX padded nodes the plain-XLA superstep beats the Pallas
-#: paths — the committed CPU sweep shows the same shape (superstep at
-#: N=256: jnp 1437us vs ell 2545us vs dense 6670us), and on TPU the kernel
-#: launch + pad overhead dominates tiles this small.  Entries are
-#: (inclusive N upper bound, backend); None = no bound.
+#: crossover table for "auto" on the TPU: jnp for tiny graphs (kernel
+#: launch + pad overhead dominates tiles this small), dense while the
+#: O(N^2) adjacency is affordable, ell beyond.  The bounds have not been
+#: measured on a chip yet (ROADMAP S2).  Entries are (inclusive N upper
+#: bound, backend); None = no bound.
 AUTO_CROSSOVER = ((512, "jnp"), (DENSE_AUTO_MAX, "dense"), (None, "ell"))
 JNP_AUTO_MAX = AUTO_CROSSOVER[0][0]
 
 
 def _on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
+
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    """Pallas mode for a kernel call: the caller's choice, else the
+    interpreter on the CPU only — every other platform compiles (and a
+    kernel that cannot lower there fails loudly, never falls back)."""
+    if interpret is not None:
+        return interpret
+    return jax.devices()[0].platform == "cpu"
 
 
 def _pad_to(x: int, mult: int) -> int:
@@ -160,6 +168,19 @@ def dense_bytes(N: int, T: int = 256) -> int:
     return Np * Np * 2
 
 
+#: narrowest ELL column bucket: a road network (max degree ~6) gathers
+#: 8 slots per row, not a 128-lane row of pads
+ELL_MIN_COLS = 8
+
+
+def _ell_cols(K: int) -> int:
+    """Kernel column width for a column bound K: the power of two >= K
+    below 128 lanes (down to `ELL_MIN_COLS`), else K padded to 128."""
+    if K <= 64:
+        return _pow2_bucket(max(1, K), floor=ELL_MIN_COLS)
+    return max(128, _pad_to(K, 128))
+
+
 def degree_bound(g) -> int:
     """pow2-bucketed max-degree threshold bound for the h-index kernels.
 
@@ -174,7 +195,7 @@ def degree_bound(g) -> int:
     if isinstance(g.deg, jax.core.Tracer) or g.N == 0:
         return Cdp
     d = int(jax.device_get(jnp.max(g.deg)))
-    return min(Cdp, _pow2_bucket(max(1, d)))
+    return min(Cdp, _pow2_bucket(max(1, d), floor=ELL_MIN_COLS))
 
 
 # ---------------------------------------------------------------------------
@@ -206,8 +227,7 @@ def hindex(
         K = max(1, N)  # h <= deg <= N-1: static, no hidden device_get
     Kp = max(128, _pad_to(K, 128))
     Tp, Np = _tile_dims(N, T)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     adj_p = _pad_dense_adj(adj, N, Np)
     est_p = jnp.full((Np,), -1, jnp.int32).at[:N].set(est.astype(jnp.int32))
     h = _hindex_pallas(adj_p, est_p, K=Kp, T=Tp, interpret=interpret)
@@ -226,21 +246,20 @@ def frontier_step(
     N, R = f.shape
     Rp = max(128, _pad_to(R, 128))
     Tp, Np = _tile_dims(N, T)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     adj_p = _pad_dense_adj(adj, N, Np)
     f_p = jnp.zeros((Np, Rp), jnp.bfloat16).at[:N, :R].set(f.astype(jnp.bfloat16))
-    e_p = jnp.zeros((Np,), jnp.int8).at[:N].set(eligible.astype(jnp.int8))
-    v_p = jnp.zeros((Np, Rp), jnp.int8).at[:N, :R].set(visited.astype(jnp.int8))
+    e_p = jnp.zeros((Np,), jnp.int32).at[:N].set(eligible.astype(jnp.int32))
+    v_p = jnp.zeros((Np, Rp), jnp.int32).at[:N, :R].set(
+        visited.astype(jnp.int32))
     nxt = _frontier_pallas(adj_p, f_p, e_p, v_p, T=Tp, interpret=interpret)
     return nxt[:N, :R]
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("kind", "K", "T", "interpret", "variant", "max_steps"))
-def _coreness_fused(mat_p, est0_p, mask_p, kind, K, T, interpret, variant,
-                    max_steps):
+    static_argnames=("kind", "K", "T", "interpret", "max_steps"))
+def _coreness_fused(mat_p, est0_p, mask_p, kind, K, T, interpret, max_steps):
     """Fused min-H fixpoint: the backend kernel inside ONE while_loop.
 
     mat_p is the padded bf16 adjacency (kind="dense") or the padded ELL
@@ -252,8 +271,7 @@ def _coreness_fused(mat_p, est0_p, mask_p, kind, K, T, interpret, variant,
     def h_of(est):
         if kind == "dense":
             return _hindex_pallas(mat_p, est, K=K, T=T, interpret=interpret)
-        return _hindex_ell_pallas(
-            mat_p, est, K=K, T=T, interpret=interpret, variant=variant)
+        return _hindex_ell_pallas(mat_p, est, K=K, T=T, interpret=interpret)
 
     def cond(c):
         _, changed, it = c
@@ -269,19 +287,18 @@ def _coreness_fused(mat_p, est0_p, mask_p, kind, K, T, interpret, variant,
     return est, steps
 
 
-def _run_fused_coreness(mat, est0, mask, N, kind, K, T, interpret, variant,
-                        max_steps):
+def _run_fused_coreness(mat, est0, mask, N, kind, K, T, interpret, max_steps):
     """Pad once (host boundary), run the fused fixpoint: (est[:N], steps)."""
     Tp, Np = _tile_dims(N, T)
     est0_p = jnp.zeros((Np,), jnp.int32).at[:N].set(est0)
     mask_p = jnp.zeros((Np,), bool).at[:N].set(mask)
     if kind == "dense":
-        mat_p, Kk = _pad_dense_adj(mat, N, Np), K
+        mat_p, Kk = _pad_dense_adj(mat, N, Np), max(128, _pad_to(K, 128))
     else:
         mat_p, Kk, Tp, Np = _pad_ell(mat, K, T)
     est_p, steps = _coreness_fused(
         mat_p, est0_p, mask_p, kind=kind, K=Kk, T=Tp, interpret=interpret,
-        variant=variant, max_steps=max_steps)
+        max_steps=max_steps)
     return est_p[:N], steps
 
 
@@ -304,11 +321,9 @@ def coreness_dense(
     N = adj.shape[0]
     deg = jnp.sum(adj > 0, axis=1).astype(jnp.int32)
     K = _pow2_bucket(int(jax.device_get(jnp.max(deg))) + 1 if N else 1)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     est, steps = _run_fused_coreness(
-        adj, deg, jnp.ones((N,), bool), N, "dense", K, T, interpret, "sort",
-        max_steps)
+        adj, deg, jnp.ones((N,), bool), N, "dense", K, T, interpret, max_steps)
     return (est, steps) if with_steps else est
 
 
@@ -322,12 +337,12 @@ def _pad_ell(nbr: jax.Array, K: Optional[int], T: int):
 
     K=None keeps the always-safe padded-Cd column bound; a max-degree K
     (left-filled rows, see `degree_bound`) shrinks the columns the kernels
-    read and sort to min(Cd, K) — the pow2 bucketing upstream keeps Ck
-    stable across maintenance streams.
+    gather and reduce to `_ell_cols(K)` (at most the padded Cd) — the
+    pow2 bucketing upstream keeps Ck stable across maintenance streams.
     """
     N, Cd = nbr.shape
     Cdp = max(128, _pad_to(Cd, 128))
-    Ck = Cdp if K is None else min(Cdp, max(128, _pad_to(K, 128)))
+    Ck = Cdp if K is None else min(Cdp, _ell_cols(K))
     Tp, Np = _tile_dims(N, T)
     Cc = min(Cd, Ck)  # source columns that can hold valid slots
     nbr_p = jnp.full((Np, Ck), -1, jnp.int32).at[:N, :Cc].set(
@@ -341,22 +356,17 @@ def hindex_ell(
     T: int = 256,
     interpret: Optional[bool] = None,
     K: Optional[int] = None,
-    variant: str = "sort",
 ) -> jax.Array:
     """h-index per node via the ELL block-sparse kernel — O(N*Cd) memory.
 
-    `variant` selects the O(Cd log Cd) in-tile sort sweep ("sort", the
-    default) or the legacy O(Cd*K) count-matrix kernel ("count", kept for
-    the variant benchmark).  K (optional) is the max-degree column bound;
-    exactness for K < Cd requires left-filled rows (`GraphBlocks`).
+    K (optional) is the max-degree column bound; exactness for K < Cd
+    requires left-filled rows (`GraphBlocks`).
     """
     N, Cd = nbr.shape
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     nbr_p, Ck, Tp, Np = _pad_ell(nbr, K, T)
     est_p = jnp.full((Np,), -1, jnp.int32).at[:N].set(est.astype(jnp.int32))
-    h = _hindex_ell_pallas(
-        nbr_p, est_p, K=Ck, T=Tp, interpret=interpret, variant=variant)
+    h = _hindex_ell_pallas(nbr_p, est_p, K=Ck, T=Tp, interpret=interpret)
     return h[:N]
 
 
@@ -371,20 +381,20 @@ def frontier_step_ell(
 ) -> jax.Array:
     """Masked BFS hop over the ELL adjacency; eligible is (N, R) per-column.
 
-    K (optional) bounds the neighbor columns swept, like `hindex_ell`.
+    K (optional) bounds the neighbor columns swept, like `hindex_ell`,
+    down to one 128-lane chunk (the kernel folds whole lane chunks).
     """
     N, Cd = nbr.shape
     R = f.shape[1]
-    Rp = max(128, _pad_to(R, 128))
-    if interpret is None:
-        interpret = not _on_tpu()
-    nbr_p, Ck, Tp, Np = _pad_ell(nbr, K, T)
-    f_p = jnp.zeros((Np, Rp), jnp.int8).at[:N, :R].set(f.astype(jnp.int8))
-    e_p = jnp.zeros((Np, Rp), jnp.int8).at[:N, :R].set(eligible.astype(jnp.int8))
-    v_p = jnp.zeros((Np, Rp), jnp.int8).at[:N, :R].set(visited.astype(jnp.int8))
-    nxt = _frontier_ell_pallas(nbr_p, f_p, e_p, v_p, K=Ck, T=Tp,
-                               interpret=interpret)
-    return nxt[:N, :R]
+    interpret = _interpret(interpret)
+    nbr_p, Ck, Tp, Np = _pad_ell(nbr, None if K is None else max(K, 128), T)
+
+    def pad(x):
+        return jnp.zeros((Np, R), bool).at[:N].set(x.astype(bool))
+
+    nxt = _frontier_ell_pallas(nbr_p, pad(f), pad(eligible), pad(visited),
+                               K=Ck, T=Tp, interpret=interpret)
+    return nxt[:N]
 
 
 def neighbor_min_ell(
@@ -401,8 +411,7 @@ def neighbor_min_ell(
     bounds the swept columns (left-filled rows, see `degree_bound`).
     """
     N, _ = nbr.shape
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     nbr_p, Ck, Tp, Np = _pad_ell(nbr, K, T)
     field_p = jnp.full((Np,), MIN_FILL, jnp.int32).at[:N].set(
         field.astype(jnp.int32))
@@ -423,8 +432,7 @@ def neighbor_sum_ell(
     rows return 0.0.  K optionally bounds the swept columns.
     """
     N, _ = nbr.shape
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     nbr_p, Ck, Tp, Np = _pad_ell(nbr, K, T)
     field_p = jnp.zeros((Np,), jnp.float32).at[:N].set(
         field.astype(jnp.float32))
@@ -454,8 +462,8 @@ def neighbor_common_ell(
     sweep.  Both are bit-identical to `ref.ell_common_ref`.
     """
     N, _ = nbr.shape
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
+    K = None if K is None else max(K, 128)  # whole lane chunks
     nbr_p, Ck, Tp, Np = _pad_ell(nbr, K, T)
     rows_p, _, _, _ = _pad_ell(rows, K, T)
     red = _common_ell_pallas(nbr_p, rows_p, K=Ck, T=Tp, interpret=interpret,
@@ -479,18 +487,25 @@ def neighbor_multi_ell(
     (`ell_multi.py`); each output is bit-identical to its standalone
     kernel.  K optionally bounds the swept columns (left-filled rows).
     """
-    from .ell_cc import MIN_FILL as _MF  # local alias; fills per combine
-    N, _ = nbr.shape
-    if interpret is None:
-        interpret = not _on_tpu()
-    nbr_p, Ck, Tp, Np = _pad_ell(nbr, K, T)
-    fills = {"min": _MF, "sum": 0.0, "hindex": -1}
+    nbr_p = _pad_ell(nbr, K, T)[0]
+    return _multi_ell_padded(nbr_p, nbr.shape[0], tuple(fields),
+                             tuple(combines), _interpret(interpret), T)
+
+
+def _multi_ell_padded(nbr_p, N: int, fields, combines, interpret: bool,
+                      T: int = 256) -> Tuple[jax.Array, ...]:
+    """`neighbor_multi_ell` on an adjacency `_pad_ell` already padded (a
+    fixpoint pads once, outside its loop): pads the (N,) fields to the
+    padded rows, reduces every field, slices back to N."""
+    Np, Ck = nbr_p.shape
+    Tp, _ = _tile_dims(N, T)
+    fills = {"min": MIN_FILL, "sum": 0.0, "hindex": -1}
     dtypes = {"min": jnp.int32, "sum": jnp.float32, "hindex": jnp.int32}
     fields_p = tuple(
         jnp.full((Np,), fills[c], dtypes[c]).at[:N].set(f.astype(dtypes[c]))
         for c, f in zip(combines, fields))
     reds = _multi_ell_pallas(
-        nbr_p, fields_p, tuple(combines), K=Ck, T=Tp, interpret=interpret)
+        nbr_p, fields_p, combines, K=Ck, T=Tp, interpret=interpret)
     return tuple(r[:N] for r in reds)
 
 
@@ -585,7 +600,7 @@ def frontier_blocks(
     if adj is None:
         adj = ref.ell_to_dense(g.nbr, g.N)
     vis_aug = visited.astype(bool) | ~elig.astype(bool)
-    ones = jnp.ones((g.N,), jnp.int8)
+    ones = jnp.ones((g.N,), bool)
     return frontier_step(adj, f, ones, vis_aug, interpret=interpret) > 0
 
 
@@ -615,7 +630,6 @@ def coreness_blocks(
     interpret: Optional[bool] = None,
     executor=None,
     with_steps: bool = False,
-    variant: str = "sort",
 ) -> Union[jax.Array, Tuple[jax.Array, jax.Array]]:
     """Full min-H coreness of every node (0 on padding rows), any backend.
 
@@ -640,13 +654,12 @@ def coreness_blocks(
         ex = executor if executor is not None else SpmdExecutor(g)
         est, steps = ex.coreness(max_steps=max_steps)
         return (est, steps) if with_steps else est
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     K = degree_bound(g)  # the single host sync of the whole fixpoint
     est0 = jnp.where(g.node_mask, g.deg, 0).astype(jnp.int32)
     mat = ref.ell_to_dense(g.nbr, g.N) if b == "dense" else g.nbr
     est, steps = _run_fused_coreness(
-        mat, est0, g.node_mask, g.N, b, K, 256, interpret, variant, max_steps)
+        mat, est0, g.node_mask, g.N, b, K, 256, interpret, max_steps)
     return (est, steps) if with_steps else est
 
 
@@ -779,13 +792,6 @@ def _combine_multi_jnp(nbr: jax.Array, fields, combines) -> Tuple:
     return tuple(outs)
 
 
-def _combine_multi_ell(nbr: jax.Array, fields, combines,
-                       interpret: Optional[bool], K: Optional[int]) -> Tuple:
-    """Fused multi reduce via the `ell_multi` Pallas kernel."""
-    return neighbor_multi_ell(
-        nbr, tuple(fields), tuple(combines), interpret=interpret, K=K)
-
-
 def _combine_multi_dense(adj: jax.Array, fields, combines, Cd: int) -> Tuple:
     """Dense multi reduce: per-combine dense forms over one resident adj.
 
@@ -845,7 +851,7 @@ def _mirror_merge(red, field, nbr, mirror, combine: str) -> jax.Array:
                re-associate across slices — allclose, not bit-equal).
       hindex — partials do NOT compose through h values; the merge
                recomputes per-slice count histograms (the
-               ``variant="count"`` formulation: cnt_t = #{values >= t},
+               threshold-count formulation: cnt_t = #{values >= t},
                t = 1..Km) which ADD exactly across slices, then reads
                h = #{t : cnt_t >= t}.  Exact because a merged h-index
                never exceeds the logical degree <= Km.
@@ -920,11 +926,14 @@ def _block_program_fused(g, state0, adj, mirror, program, b: str,
             if b == "jnp":
                 return _combine_multi_jnp(g.nbr, field, program.combines)
             if b == "ell":
-                return _combine_multi_ell(g.nbr, field, program.combines,
-                                          interpret, None)
+                return _multi_ell_padded(adj, g.N, field, program.combines,
+                                         interpret)
             return _combine_multi_dense(adj, field, program.combines, g.Cd)
         if b == "jnp":
             return _combine_jnp(g.nbr, field, program.combine)
+        if b == "ell" and program.combine in MULTI_COMBINES:
+            return _multi_ell_padded(adj, g.N, (field,), (program.combine,),
+                                     interpret)[0]
         if b == "ell":
             return _combine_ell(g.nbr, field, program.combine, interpret,
                                 None)
@@ -1048,9 +1057,14 @@ def run_block_program(
             max_supersteps=ms)
         steps = jnp.int32(len(eng.traces))
         return (state, steps) if with_steps else state
-    if interpret is None:
-        interpret = not _on_tpu()
-    adj = ref.ell_to_dense(g.nbr, g.N) if b == "dense" else None
+    interpret = _interpret(interpret)
+    # the loop's adjacency operand, built once outside it: the dense
+    # matrix, or the ELL lists padded to tiles and cut to the degree bound
+    adj = None
+    if b == "dense":
+        adj = ref.ell_to_dense(g.nbr, g.N)
+    elif b == "ell" and program.combine != "count_common":
+        adj = _pad_ell(g.nbr, degree_bound(g), 256)[0]
     state, steps = _block_program_fused(
         g, state0, adj, mirror, program=program, b=b, interpret=interpret,
         max_steps=ms, n_real=n_real)

@@ -70,18 +70,17 @@ def make_step(bundle, ocfg, cfg, grad_compression: bool, mesh):
 
     # int8-compressed DP gradient sync: per-shard grads + compressed psum
     # inside shard_map over the data axis, then the optimizer update.
-    from jax.experimental.shard_map import shard_map
     from repro.optim.compress import compressed_psum_mean
 
     dp = SH.dp_axes(mesh)
 
     def train_step(params, opt_state, ef, batch):
         @partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(), jax.tree.map(lambda _: P(), ef),
                       jax.tree.map(lambda _: P(dp), batch)),
             out_specs=(P(), P(), jax.tree.map(lambda _: P(), ef)),
-            check_rep=False,
+            check_vma=False,
         )
         def grads_sync(p, ef_, local_batch):
             def loss_of(pp):

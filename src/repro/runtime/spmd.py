@@ -43,7 +43,6 @@ from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from ..kernels.ops import BlockCtx
@@ -167,9 +166,9 @@ def _smap(fn, mesh, n_lead: int, n_rep: int, out_specs):
     global _STEP_BUILDS
     _STEP_BUILDS += 1
     specs = [P_(AXIS)] * n_lead + [P_()] * n_rep + [P_(AXIS)] * 3
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=tuple(specs), out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -312,12 +311,36 @@ class SpmdExecutor:
         self._refresh(g)
 
     def _refresh(self, g) -> None:
-        """Re-stage the plan tables and per-node fields on device."""
-        self.node_mask = jnp.asarray(g.node_mask)
-        self.deg = jnp.asarray(g.deg, jnp.int32)
-        self._nbrl = jnp.asarray(self.plan.nbr_local)
-        self._send = jnp.asarray(self.plan.send_idx)
-        self._recv = jnp.asarray(self.plan.recv_pos)
+        """Re-stage the plan tables and per-node fields on device, each
+        split over the worker mesh along its leading axis (every device
+        holds only its own shard)."""
+        sh = self.wm.node_sharding()
+        self.node_mask = jax.device_put(jnp.asarray(g.node_mask), sh)
+        self.deg = jax.device_put(jnp.asarray(g.deg, jnp.int32), sh)
+        self._nbrl = jax.device_put(
+            self.plan.nbr_local[:, :self._cols()], sh)
+        self._send = jax.device_put(self.plan.send_idx, sh)
+        self._recv = jax.device_put(self.plan.recv_pos, sh)
+
+    def place(self, g):
+        """`g` with its per-node arrays split over the worker mesh like the
+        plan tables: each device holds its own blocks' rows of the graph
+        the stream's apply path edits, not a whole copy on the first."""
+        return jax.device_put(g, self.wm.node_sharding())
+
+    def _cols(self) -> int:
+        """Adjacency columns the supersteps gather: the power of two
+        (>= 8) above the widest row, at most Cd — a road network's rows
+        hold ~4 of Cd = 70 slots.  Rows are left-filled (the sorted-ELL
+        invariant), so column j holds a neighbor somewhere iff some row
+        is wider than j: one column read per bucket finds the width on
+        the host, with no device transfer."""
+        nl = self.plan.nbr_local
+        pad = nl.shape[0] // self.wm.W + self.plan.H + 1  # S + H + 1
+        cols = 8
+        while cols < nl.shape[1] and (nl[:, cols] != pad).any():
+            cols *= 2
+        return min(cols, nl.shape[1])
 
     def apply_updates(self, g, edits) -> None:
         """Incrementally maintain the halo plan after edge `edits`.
@@ -651,7 +674,7 @@ class SpmdEngine:
         ex = self.ex
         H = ex.plan.H
         B, Cn = ex.wm.B, ex.wm.Cn
-        Cd = ex.plan.nbr_local.shape[1]
+        Cd = ex._nbrl.shape[1]
         overlap = ex.overlap
         mirror = getattr(program, "mirror_uid", None)
         key = (ex.wm.mesh, H, B, Cn, Cd, overlap, program, mirror)
@@ -682,7 +705,7 @@ class SpmdEngine:
         ex = self.ex
         H = ex.plan.H
         B, Cn = ex.wm.B, ex.wm.Cn
-        Cd = ex.plan.nbr_local.shape[1]
+        Cd = ex._nbrl.shape[1]
         overlap = ex.overlap
         mirror = getattr(program, "mirror_uid", None)
         key = ("fused", ex.wm.mesh, H, B, Cn, Cd, overlap, program, mirror)
@@ -730,7 +753,7 @@ class SpmdEngine:
         hint = getattr(program, "summary_shape", None)
         if hint is not None:
             return hint()
-        Cd = self.ex.plan.nbr_local.shape[1]
+        Cd = self.ex._nbrl.shape[1]
         field_s = jax.eval_shape(program.halo_field, wstate)
         nb_s = jax.tree_util.tree_map(
             lambda fs: jax.ShapeDtypeStruct(

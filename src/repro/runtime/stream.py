@@ -287,7 +287,7 @@ class StreamSession:
                              else kd._spmd_executor(g, W))
         self._ex_updates0 = self.executor.plan_updates if spmd else 0
         self._ex_rebuilds0 = self.executor.full_rebuilds if spmd else 0
-        self.g = g
+        self.g = self.executor.place(g) if spmd else g
         self.core = jnp.asarray(core)
         self._track_labels = cc_labels is not None
         self.labels = (jnp.asarray(cc_labels) if self._track_labels

@@ -327,8 +327,9 @@ def test_restore_across_mesh_shapes(tmp_path):
 def test_e2e_elastic_acceptance(tmp_path):
     """Start at tight capacities; TRIPLE the edge count via automatic
     escalation; checkpoint; lose a worker mid-stream; recover onto the
-    surviving mesh; keep streaming.  Final (core, labels, pagerank) are
-    bit-identical to a from-scratch recompute, and compiled-cache
+    surviving mesh; keep streaming.  Final (core, labels) are
+    bit-identical to a from-scratch recompute, pagerank equal to one
+    within float32 rounding, and compiled-cache
     re-specialization is counter-bounded: at most one per grow, zero in
     steady state."""
     nd = jax.device_count()
@@ -385,7 +386,14 @@ def test_e2e_elastic_acceptance(tmp_path):
     _assert_exact_vs_recompute(final)
     state = AnalyticsState(final, pr_steps=PR_STEPS)
     snap = state.snapshot
-    np.testing.assert_array_equal(
+    # the oracle is a from-scratch pagerank on the default (jnp) backend,
+    # independent of the session's mesh executor.  The two float32 sums
+    # re-associate differently under XLA:CPU on JAX 0.9 (max relative
+    # difference 1.95e-7 measured, about 2 ulp; the tree at the parent
+    # commit with only the shard_map import repaired shows the same), so
+    # the check is to 1e-6 relative.
+    np.testing.assert_allclose(
         np.asarray(snap.rank),
-        np.asarray(pagerank(final.g, tol=None, max_steps=PR_STEPS)))
+        np.asarray(pagerank(final.g, tol=None, max_steps=PR_STEPS)),
+        rtol=1e-6, atol=0.0)
     assert snap.grows == final._grows >= grows_p1

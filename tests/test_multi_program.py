@@ -57,8 +57,9 @@ def test_fused_runs_exactly_steps_supersteps(g):
 def _lower(g, program, b):
     """Force a fresh trace of the fused superstep loop (no jit cache)."""
     state0 = program.init(g)
+    adj = ops._pad_ell(g.nbr, None, 256)[0] if b == "ell" else None
     ops._block_program_fused.lower(
-        g, state0, None, None, program=program, b=b, interpret=True,
+        g, state0, adj, None, program=program, b=b, interpret=True,
         max_steps=5, n_real=int(g.n_real))
 
 

@@ -77,7 +77,6 @@ def test_error_feedback_removes_bias():
 
 def test_compressed_psum_under_shard_map():
     from functools import partial
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.optim.compress import compressed_psum_mean
     from repro.launch.mesh import make_test_mesh
@@ -86,8 +85,8 @@ def test_compressed_psum_under_shard_map():
     grads = {"w": jnp.arange(8, dtype=jnp.float32)}
     ef = init_error_feedback(grads)
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
+             check_vma=False)
     def f(g, e):
         return compressed_psum_mean(g, e, "data")
 

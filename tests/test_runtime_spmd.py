@@ -152,6 +152,48 @@ def test_coreness_spmd_bit_identical(P):
             assert (ref_core == got).all(), (name, P, W)
 
 
+def test_executor_gathers_degree_bucket_columns():
+    """The mesh supersteps gather only the pow2 bucket (>= 8) above the
+    widest row, not all Cd slots, and stay exact."""
+    for name, edges, n in _graphs():
+        g = _blocks(edges, n, 4)
+        want = np.asarray(ops.coreness_blocks(g, backend="jnp"))
+        width = int(np.asarray(g.deg).max())
+        for W in _worker_counts(4):
+            ex = SpmdExecutor(g, W=W)
+            cols = ex._nbrl.shape[1]
+            assert cols == min(g.Cd, ops._pow2_bucket(width, floor=8))
+            np.testing.assert_array_equal(
+                np.asarray(ex.coreness()[0]), want, err_msg=f"{name} W={W}")
+
+
+def test_session_graph_split_over_mesh():
+    """A mesh session's graph (the apply path's copy) is split over the
+    worker mesh like the plan tables, before and after a window."""
+    from repro.runtime import StreamSession
+
+    _, edges, n = _graphs()[1]
+    g = _blocks(edges, n, 4)
+    core = ops.coreness_blocks(g, backend="jnp")
+    W = _worker_counts(4)[-1]
+    sess = StreamSession(_clone(g), core, R=4, backend="ell_spmd", W=W)
+
+    def shards(x):
+        got = [(s.device, s.data.nbytes) for s in x.addressable_shards]
+        assert len({d for d, _ in got}) == W
+        assert all(b * W == x.nbytes for _, b in got), got
+
+    for a in (sess.g.nbr, sess.g.deg, sess.g.node_mask):
+        shards(a)
+    nb = np.asarray(g.nbr)
+    u = int(np.flatnonzero(np.asarray(g.node_mask))[0])
+    sess.apply_window([(u, int(nb[u][nb[u] >= 0][0]), -1)])
+    shards(sess.g.nbr)
+    np.testing.assert_array_equal(
+        np.asarray(sess.core),
+        np.asarray(ops.coreness_blocks(sess.g, backend="jnp")))
+
+
 def test_hindex_and_frontier_dispatch_parity():
     _, edges, n = _graphs()[0]
     g = _blocks(edges, n, 4)

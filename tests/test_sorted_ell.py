@@ -49,21 +49,21 @@ def _random_graph(n, seed, P=4, m=3):
                         P=P, deg_slack=16)
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(10, 60), st.integers(0, 10_000))
 def test_build_blocks_sorted(n, seed):
     g = _random_graph(n, seed)
     assert_sorted_ell(g.nbr, g.deg)
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(32, 200), st.integers(0, 10_000))
 def test_build_ell_random_sorted(N, seed):
     g = build_ell_random(N, Cd=16, seed=seed)
     assert_sorted_ell(g.nbr, g.deg)
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(st.integers(16, 50), st.integers(0, 10_000),
        st.sampled_from(["intra", "inter"]))
 def test_mutations_preserve_invariant_and_host_jit_parity(n, seed, scen):
@@ -84,7 +84,7 @@ def test_mutations_preserve_invariant_and_host_jit_parity(n, seed, scen):
                                   np.asarray(g_host.deg))
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(20, 60), st.integers(0, 10_000))
 def test_migration_preserves_invariant(n, seed):
     g = _random_graph(n, seed)
@@ -123,7 +123,7 @@ def _ragged_rows(n, cd, seed):
     return jnp.asarray(nbr)
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(2, 50), st.integers(1, 12), st.integers(0, 10_000))
 def test_merge_matches_oracle_ragged(n, cd, seed):
     nbr = _ragged_rows(n, cd, seed)

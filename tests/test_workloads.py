@@ -287,14 +287,17 @@ def count_device_get(monkeypatch):
 def test_workload_fixpoints_transfer_count_is_o1(count_device_get):
     g = _path_graph()
     for b in ("jnp", "dense", "ell"):
+        # the ELL fixpoint reads its degree bound once, before the loop
+        # (like `coreness_blocks`); no backend transfers per superstep
+        entry = 1 if b == "ell" else 0
         count_device_get["n"] = 0
         labels, steps = connected_components(g, backend=b, with_steps=True)
-        assert count_device_get["n"] == 0, (b, count_device_get["n"])
+        assert count_device_get["n"] == entry, (b, count_device_get["n"])
         assert hasattr(steps, "dtype")  # device scalar, not a host int
         assert int(steps) > 20, (b, int(steps))
         count_device_get["n"] = 0
         pagerank(g, tol=1e-8, max_steps=300, backend=b)
-        assert count_device_get["n"] == 0, (b, count_device_get["n"])
+        assert count_device_get["n"] == entry, (b, count_device_get["n"])
         count_device_get["n"] = 0
         triangle_counts(g, backend=b)
         assert count_device_get["n"] == 0, (b, count_device_get["n"])
