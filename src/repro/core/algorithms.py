@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..kernels import ops
 from .engine import BlockCtx, BlockProgram, MultiProgram
 from .graph import GraphBlocks
@@ -75,6 +76,7 @@ class ConnectedComponentsProgram(BlockProgram):
     combine = "min"
     halo_fill = INT32_MAX
     max_steps = 10_000
+    name = "cc"
 
     def init(self, g: GraphBlocks) -> jax.Array:
         return jnp.where(g.node_mask, jnp.arange(g.N, dtype=jnp.int32),
@@ -100,6 +102,7 @@ class PageRankProgram(BlockProgram):
 
     combine = "sum"
     halo_fill = 0.0
+    name = "pagerank"
 
     def __init__(self, alpha: float = 0.85, tol: Optional[float] = 1e-6,
                  max_steps: int = 100):
@@ -140,6 +143,7 @@ class TriangleCountProgram(BlockProgram):
     combine = "count_common"
     halo_fill = -1
     max_steps = 1  # a single exchange computes every count
+    name = "triangles"
 
     def init(self, g: GraphBlocks):
         return jnp.zeros(g.N, jnp.int32), jnp.asarray(g.nbr, jnp.int32)
@@ -164,6 +168,7 @@ class CorenessBlockProgram(BlockProgram):
     combine = "hindex"
     halo_fill = -1
     max_steps = 10_000
+    name = "coreness"
 
     def init(self, g: GraphBlocks) -> jax.Array:
         return jnp.where(g.node_mask, g.deg, 0).astype(jnp.int32)
@@ -259,6 +264,7 @@ def triangle_counts(
     return out[0]
 
 
+@tracing.span("analytics.fused")
 def fused_analytics(
     g: GraphBlocks,
     alpha: float = 0.85,
@@ -328,6 +334,7 @@ def triangle_total(counts: jax.Array) -> jax.Array:
 
 
 @jax.jit
+@jax.named_scope("labels")
 def merge_labels(labels: jax.Array, us: jax.Array, vs: jax.Array,
                  valid: jax.Array) -> jax.Array:
     """Exact CC maintenance for a fixed-width batch of edge INSERTIONS.

@@ -170,6 +170,9 @@ class BlockProgram:
     #: superstep bound (the whole loop is device-resident; the bound is a
     #: loop-carried operand, never a host decision)
     max_steps: int = 10_000
+    #: the program's name in compiled-program and named-scope names (a
+    #: profile's ops read ``.../<name>/...``); not part of its identity
+    name: str = "program"
 
     def _key(self) -> Tuple:
         """Static identity: every parameter that changes traced behavior."""
@@ -265,6 +268,7 @@ class MultiProgram(BlockProgram):
                     f"expected one of {MULTI_COMBINES}")
         self.programs = programs
         self.combines: Tuple[str, ...] = tuple(p.combine for p in programs)
+        self.name = "_".join(p.name for p in programs)
         self.halo_fill = tuple(p.halo_fill for p in programs)
         self.max_steps = int(max_steps)
 
@@ -279,9 +283,11 @@ class MultiProgram(BlockProgram):
 
     def update(self, ctx: "BlockCtx", state: Tuple[Any, ...],
                red: Tuple[jax.Array, ...]) -> Tuple[Any, ...]:
-        return tuple(
-            p.update(ctx, s, r)
-            for p, s, r in zip(self.programs, state, red))
+        out = []
+        for p, s, r in zip(self.programs, state, red):
+            with jax.named_scope(p.name):
+                out.append(p.update(ctx, s, r))
+        return tuple(out)
 
     def changed(self, old: Tuple[Any, ...],
                 new: Tuple[Any, ...]) -> jax.Array:

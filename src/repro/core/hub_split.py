@@ -468,6 +468,7 @@ class _RawCommonProgram:
     combine = "count_common"
     halo_fill = -1
     max_steps = 1
+    name = "triangles"
 
     def __hash__(self):
         return hash(type(self))

@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..kernels import ops
 from .graph import GraphBlocks, insert_edge, delete_edge
 
@@ -122,6 +123,7 @@ def k_reachable(
     return visited[:, 0], steps
 
 
+@jax.named_scope("reach")
 def k_reachable_batch(
     g: GraphBlocks, core: jax.Array, roots: jax.Array, ks: jax.Array,
     max_steps: int = 10_000, backend: str = "jnp",
@@ -153,6 +155,7 @@ def k_reachable_batch(
     return visited, steps
 
 
+@jax.named_scope("recompute")
 def _restricted_recompute(
     g: GraphBlocks, est0: jax.Array, cand: jax.Array,
     max_steps: int = 10_000, backend: str = "jnp",
@@ -306,6 +309,7 @@ def _independent_prefix(cand: np.ndarray, valid: int) -> Tuple[List[int], List[i
     return accepted, deferred
 
 
+@jax.named_scope("apply_edges")
 def _apply_edges(
     g: GraphBlocks, us: jax.Array, vs: jax.Array, ops_: jax.Array
 ) -> GraphBlocks:
@@ -571,6 +575,7 @@ def maintain_batch(
     return g, core, stats
 
 
+@tracing.span("stream.coordinator")
 def _maintain_one(g, core, update, tot, backend, W=None, ex=None):
     """Sequential fallback for one update; accumulates into `tot`."""
     if backend == SPMD_BACKEND:

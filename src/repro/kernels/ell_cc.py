@@ -58,5 +58,5 @@ def neighbor_min_ell(
     C = min(Cd, K)
     (red,) = ell_row_call(_ell_min_kernel, nbr[:, :C],
                           (field.astype(jnp.int32),), (MIN_FILL,),
-                          (jnp.int32,), T, interpret)
+                          (jnp.int32,), T, interpret, name="ell_cc")
     return red

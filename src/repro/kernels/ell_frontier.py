@@ -100,6 +100,7 @@ def frontier_step_ell(
     kernel = functools.partial(_ell_frontier_kernel, C=C)
     words = [
         ell_row_call(kernel, nbr[:, :C], (fw[:, w],), (0,), (jnp.int32,), T,
-                     interpret, row_args=(ew[:, w:w + 1], vw[:, w:w + 1]))[0]
+                     interpret, row_args=(ew[:, w:w + 1], vw[:, w:w + 1]),
+                     name="ell_frontier")[0]
         for w in range(fw.shape[1])]
     return unpack_words(jnp.stack(words, axis=1), R)

@@ -60,13 +60,14 @@ ROW_CHUNK = 1 << 18
 
 
 def ell_row_call(kernel, nbr, fields, fills, out_dtypes, T: int,
-                 interpret: bool, row_args=()):
+                 interpret: bool, row_args=(), *, name: str):
     """The ELL family's launch: gather every field through `nbr` in XLA
     (`vals[u, j] = field[nbr[u, j]]`, PAD slots -> that field's fill),
     then run `kernel` over (T, C) row tiles of the gathered values (plus
     (T, k) tiles of each (N, k) `row_args` entry), one (N,) output per
     entry of `out_dtypes`.  The gather keeps the (N,) fields out of the
-    kernel's VMEM blocks.
+    kernel's VMEM blocks.  In a profile the gathers sit under the named
+    scope ``gather`` and the kernel under `name`, its module's name.
 
     Nodes are processed in chunks of `ROW_CHUNK` rows inside one
     `fori_loop`; the last chunk's start is clamped to N - rows, so it
@@ -78,27 +79,30 @@ def ell_row_call(kernel, nbr, fields, fills, out_dtypes, T: int,
     def call(nb, *extra):
         ok, idx = nb >= 0, jnp.clip(nb, 0)
         vals = []
-        for f, fl in zip(fields, fills):
-            # one gather at a time: side by side, XLA stages only one of
-            # the (N,) fields in VMEM and the others gather from HBM;
-            # serialized, each field gets VMEM for its own gather
-            f, idx = jax.lax.optimization_barrier((f, idx))
-            vals.append(jnp.where(ok, f[idx], jnp.asarray(fl, f.dtype)))
-            idx, vals[-1] = jax.lax.optimization_barrier((idx, vals[-1]))
-        outs = pl.pallas_call(
-            kernel,
-            grid=(rows // T,),
-            in_specs=[pl.BlockSpec((T, C), lambda i: (i, 0)) for _ in vals]
-            + [pl.BlockSpec((T, a.shape[1]), lambda i: (i, 0))
-               for a in extra],
-            out_specs=[pl.BlockSpec((T, 1), lambda i: (i, 0))
-                       for _ in out_dtypes],
-            out_shape=[jax.ShapeDtypeStruct((rows, 1), d)
-                       for d in out_dtypes],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel",)),
-            interpret=interpret,
-        )(*vals, *extra)
+        with jax.named_scope("gather"):
+            for f, fl in zip(fields, fills):
+                # one gather at a time: side by side, XLA stages only one
+                # of the (N,) fields in VMEM and the others gather from
+                # HBM; serialized, each field gets VMEM for its own gather
+                f, idx = jax.lax.optimization_barrier((f, idx))
+                vals.append(jnp.where(ok, f[idx], jnp.asarray(fl, f.dtype)))
+                idx, vals[-1] = jax.lax.optimization_barrier((idx, vals[-1]))
+        with jax.named_scope(name):
+            outs = pl.pallas_call(
+                kernel,
+                grid=(rows // T,),
+                in_specs=[pl.BlockSpec((T, C), lambda i: (i, 0))
+                          for _ in vals]
+                + [pl.BlockSpec((T, a.shape[1]), lambda i: (i, 0))
+                   for a in extra],
+                out_specs=[pl.BlockSpec((T, 1), lambda i: (i, 0))
+                           for _ in out_dtypes],
+                out_shape=[jax.ShapeDtypeStruct((rows, 1), d)
+                           for d in out_dtypes],
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("parallel",)),
+                interpret=interpret,
+            )(*vals, *extra)
         return tuple(o[:, 0] for o in outs)
 
     sliced = (nbr,) + tuple(row_args)
@@ -161,6 +165,7 @@ def hindex_ell(
     assert N % T == 0, (N, T)
     check_cols(Cd, K)
     C = min(Cd, K)  # columns actually read
-    (h,) = ell_row_call(_ell_hindex_bisect_kernel, nbr[:, :C], (est.astype(jnp.int32),), (-1,),
-                        (jnp.int32,), T, interpret)
+    (h,) = ell_row_call(_ell_hindex_bisect_kernel, nbr[:, :C],
+                        (est.astype(jnp.int32),), (-1,), (jnp.int32,), T,
+                        interpret, name="ell_hindex")
     return h

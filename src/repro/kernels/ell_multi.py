@@ -84,4 +84,5 @@ def neighbor_multi_ell(
     return ell_row_call(
         functools.partial(_ell_multi_kernel, combines=combines), nbr[:, :C],
         tuple(f.astype(d) for f, d in zip(fields, dtypes)),
-        tuple(_FIELD_SPEC[c][1] for c in combines), dtypes, T, interpret)
+        tuple(_FIELD_SPEC[c][1] for c in combines), dtypes, T, interpret,
+        name="ell_multi")

@@ -53,5 +53,5 @@ def neighbor_sum_ell(
     C = min(Cd, K)
     (red,) = ell_row_call(_ell_sum_kernel, nbr[:, :C],
                           (field.astype(jnp.float32),), (0.0,),
-                          (jnp.float32,), T, interpret)
+                          (jnp.float32,), T, interpret, name="ell_pagerank")
     return red

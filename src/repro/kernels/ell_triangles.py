@@ -133,6 +133,7 @@ def _ell_allpairs_kernel(nbr_ref, own_ref, rows_ref, out_ref, *, C: int, T: int)
 
 @functools.partial(
     jax.jit, static_argnames=("K", "T", "interpret", "variant"))
+@jax.named_scope("ell_triangles")
 def neighbor_common_ell(
     nbr: jax.Array,
     rows: jax.Array,

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
+from .. import tracing
 from ..kernels.ops import BlockCtx
 from ..kernels.ref import combine_rows, hindex_rows
 from .halo import HaloPlan, build_halo_plan
@@ -120,23 +121,29 @@ def _exchange_gather(field, nbrl, send, recv, H, fill, overlap: bool = False):
     slots gather straight from `field` — same values, one fewer
     serialized collective phase per superstep.
     """
-    halo = _halo_exchange(field, send[0], recv[0], H, fill)
-    if overlap:
-        return _overlap_select(field, halo, nbrl)
-    return _neighbor_vals(field, halo, nbrl)
+    with jax.named_scope("halo"):
+        halo = _halo_exchange(field, send[0], recv[0], H, fill)
+    with jax.named_scope("gather"):
+        if overlap:
+            return _overlap_select(field, halo, nbrl)
+        return _neighbor_vals(field, halo, nbrl)
 
 
-def _gather_field(field, nbrl, send, recv, H, fill, overlap: bool):
+def _gather_field(field, nbrl, send, recv, H, fill, overlap: bool, names):
     """`_exchange_gather` over a declared halo field, tuple-aware.
 
     MultiPrograms declare tuple fields/fills (one per fused sub-program);
-    each leaf exchanges with its own fill and dtype.
+    each leaf exchanges with its own fill and dtype, under the named
+    scope of its sub-program (`names`).
     """
     if isinstance(field, tuple):
-        return tuple(
-            _exchange_gather(f, nbrl, send, recv, H,
-                             jnp.asarray(fl, f.dtype), overlap)
-            for f, fl in zip(field, fill))
+        out = []
+        for f, fl, name in zip(field, fill, names):
+            with jax.named_scope(name):
+                out.append(_exchange_gather(
+                    f, nbrl, send, recv, H, jnp.asarray(fl, f.dtype),
+                    overlap))
+        return tuple(out)
     return _exchange_gather(field, nbrl, send, recv, H,
                             jnp.asarray(fill, field.dtype), overlap)
 
@@ -160,11 +167,14 @@ def step_build_count() -> int:
     return _STEP_BUILDS
 
 
-def _smap(fn, mesh, n_lead: int, n_rep: int, out_specs):
+def _smap(fn, mesh, n_lead: int, n_rep: int, out_specs, name: str):
     """shard_map + jit: `n_lead` node-sharded args, `n_rep` replicated args,
-    then the three plan tables (nbr_local / send / recv, worker-sharded)."""
+    then the three plan tables (nbr_local / send / recv, worker-sharded).
+    `name` names the compiled program (``jit_<name>`` in a profile's
+    ``XLA Modules`` line), so each mesh step is told apart there."""
     global _STEP_BUILDS
     _STEP_BUILDS += 1
+    fn.__name__ = fn.__qualname__ = name
     specs = [P_(AXIS)] * n_lead + [P_()] * n_rep + [P_(AXIS)] * 3
     return jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=tuple(specs), out_specs=out_specs,
@@ -179,7 +189,7 @@ def _compiled_hindex(mesh, H: int, overlap: bool):
                                 overlap)
         return hindex_rows(vals)
 
-    return _smap(local, mesh, 1, 0, P_(AXIS))
+    return _smap(local, mesh, 1, 0, P_(AXIS), "spmd_hindex")
 
 
 @functools.lru_cache(maxsize=128)
@@ -189,11 +199,12 @@ def _compiled_frontier(mesh, H: int, overlap: bool):
             f.astype(jnp.int8), nbrl, send, recv, H, jnp.int8(0), overlap)
         return jnp.any(vals > 0, axis=1) & elig & ~vis
 
-    return _smap(local, mesh, 3, 0, P_(AXIS))
+    return _smap(local, mesh, 3, 0, P_(AXIS), "spmd_frontier")
 
 
 @functools.lru_cache(maxsize=128)
 def _compiled_coreness(mesh, H: int, overlap: bool):
+    @jax.named_scope("coreness")
     def local(est, mask, max_steps, nbrl, send, recv):
         def cond(c):
             _, changed, it = c
@@ -210,11 +221,12 @@ def _compiled_coreness(mesh, H: int, overlap: bool):
             cond, body, (est, jnp.bool_(True), jnp.int32(0)))
         return est, steps
 
-    return _smap(local, mesh, 2, 1, (P_(AXIS), P_()))
+    return _smap(local, mesh, 2, 1, (P_(AXIS), P_()), "spmd_coreness")
 
 
 @functools.lru_cache(maxsize=128)
 def _compiled_reach(mesh, H: int, overlap: bool):
+    @jax.named_scope("reach")
     def local(core, mask, roots, ks, max_steps, nbrl, send, recv):
         elig = (core[:, None] == ks[None, :]) & mask[:, None]
         visited0 = roots & elig
@@ -236,11 +248,12 @@ def _compiled_reach(mesh, H: int, overlap: bool):
             (visited0, visited0, _any_global(visited0), jnp.int32(0)))
         return visited, steps
 
-    return _smap(local, mesh, 3, 2, (P_(AXIS), P_()))
+    return _smap(local, mesh, 3, 2, (P_(AXIS), P_()), "spmd_reach")
 
 
 @functools.lru_cache(maxsize=128)
 def _compiled_recompute(mesh, H: int, overlap: bool):
+    @jax.named_scope("recompute")
     def local(est, cand, mask, max_steps, nbrl, send, recv):
         move = cand & mask
 
@@ -259,7 +272,7 @@ def _compiled_recompute(mesh, H: int, overlap: bool):
             cond, body, (est, jnp.bool_(True), jnp.int32(0)))
         return est, steps
 
-    return _smap(local, mesh, 3, 1, (P_(AXIS), P_()))
+    return _smap(local, mesh, 3, 1, (P_(AXIS), P_()), "spmd_recompute")
 
 
 class LocalCtx(NamedTuple):
@@ -342,6 +355,7 @@ class SpmdExecutor:
             cols *= 2
         return min(cols, nl.shape[1])
 
+    @tracing.span("halo.update")
     def apply_updates(self, g, edits) -> None:
         """Incrementally maintain the halo plan after edge `edits`.
 
@@ -354,6 +368,7 @@ class SpmdExecutor:
         self._refresh(g)
         self.plan_updates += 1
 
+    @tracing.span("halo.rebuild")
     def rebuild(self, g) -> None:
         """Full from-scratch plan rebuild (e.g. after `migrate_vertices`
         permuted the blocks).  Keeps the H/K capacity floors so compiled
@@ -363,6 +378,7 @@ class SpmdExecutor:
         self._refresh(g)
         self.full_rebuilds += 1
 
+    @tracing.span("halo.rebuild")
     def grow(self, g) -> None:
         """Follow a capacity escalation (`core.graph.grow_blocks`): refit
         the worker mesh to the new Cn — same W, same devices, only the
@@ -460,6 +476,11 @@ class SpmdProgram:
     #: value PAD / dump slots read as (must match the field dtype)
     halo_fill = -1
 
+    #: names the compiled steps (``spmd_fused_<name>``) and, for a tuple
+    #: halo field, each leaf's named scope (`field_names`)
+    name = "program"
+    field_names = None
+
     #: True iff worker_local AND master_compute are jit-pure with
     #: structure-stable state (mstate/directive pytrees keep their shape
     #: across supersteps) — `SpmdEngine.run_spmd` then fuses the whole
@@ -492,6 +513,7 @@ class SpmdCorenessProgram(SpmdProgram):
 
     halo_fill = -1
     fusable = True  # pure worker/master ops: the loop runs on-device
+    name = "coreness"
 
     # stateless: any two instances are interchangeable, so they share the
     # engine's compiled-step cache entry
@@ -589,6 +611,9 @@ class SpmdBlockProgram(SpmdProgram):
         self.prog = prog
         self.n_real = int(n_real)
         self.halo_fill = prog.halo_fill
+        self.name = prog.name
+        if prog.combine == "multi":
+            self.field_names = tuple(p.name for p in prog.programs)
         self.mirror = mirror
         self.mirror_uid = None if mirror is None else mirror.uid
 
@@ -627,9 +652,12 @@ class SpmdBlockProgram(SpmdProgram):
             # fused lockstep supersteps: one exchange per sub-field, one
             # shared halt reduction — per-field reduces are the standalone
             # formulations, so results match sub-programs run alone.
-            red = tuple(
-                combine_rows(c, f, nb) for c, f, nb
-                in zip(self.prog.combines, field, nb_vals))
+            red = []
+            for c, f, nb, name in zip(self.prog.combines, field, nb_vals,
+                                      self.field_names):
+                with jax.named_scope(name):
+                    red.append(combine_rows(c, f, nb))
+            red = tuple(red)
         else:
             red = combine_rows(self.prog.combine, field, nb_vals)
         if self.mirror is not None:
@@ -685,11 +713,13 @@ class SpmdEngine:
         def local(wstate, deg, mask, directive, nbrl, send, recv):
             field = program.halo_field(wstate)
             nb_vals = _gather_field(
-                field, nbrl, send, recv, H, program.halo_fill, overlap)
+                field, nbrl, send, recv, H, program.halo_fill, overlap,
+                program.field_names)
             ctx = LocalCtx(deg=deg, node_mask=mask, B=B, Cn=Cn, Cd=Cd)
             return program.worker_local(ctx, wstate, nb_vals, directive)
 
-        fn = _smap(local, ex.wm.mesh, 3, 1, (P_(AXIS), P_(AXIS)))
+        fn = _smap(local, ex.wm.mesh, 3, 1, (P_(AXIS), P_(AXIS)),
+                   f"spmd_step_{program.name}")
         self._step_cache[key] = fn
         return fn
 
@@ -725,7 +755,8 @@ class SpmdEngine:
                 wstate, mstate, d, _, it = c
                 field = program.halo_field(wstate)
                 nb_vals = _gather_field(
-                    field, nbrl, send, recv, H, program.halo_fill, overlap)
+                    field, nbrl, send, recv, H, program.halo_fill, overlap,
+                    program.field_names)
                 wstate2, summary = program.worker_local(
                     ctx, wstate, nb_vals, d)
                 full = jax.lax.all_gather(summary, AXIS, axis=0, tiled=True)
@@ -739,7 +770,8 @@ class SpmdEngine:
                 (wstate, mstate, directive, jnp.bool_(False), jnp.int32(0)))
             return wstate, mstate, n
 
-        fn = _smap(local, ex.wm.mesh, 3, 3, (P_(AXIS), P_(), P_()))
+        fn = _smap(local, ex.wm.mesh, 3, 3, (P_(AXIS), P_(), P_()),
+                   f"spmd_fused_{program.name}")
         self._step_cache[key] = fn
         return fn
 

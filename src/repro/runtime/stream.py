@@ -72,6 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..core import kcore_dynamic as kd
 from ..core import partition_dynamic as pd
 from ..core.algorithms import connected_components, merge_labels
@@ -100,6 +101,8 @@ class StreamStats(NamedTuple):
     cc_merges: int = 0           # CC labels maintained by O(1) label merges
     cc_recomputes: int = 0       # CC label recomputations (delete/migration)
     grows: int = 0               # capacity escalations (Cn/Cd pad-and-rekey)
+    candidates: int = 0          # nodes the k-reachable searches reached,
+                                 # summed over updates (all paths)
 
     @property
     def escalated(self) -> int:
@@ -185,9 +188,11 @@ class RouteMasks(NamedTuple):
     cand_ins: jax.Array      # (N,) bool — union candidates of accepted inserts
     cand_del: jax.Array      # (N,) bool — union candidates of accepted deletes
     per_block: jax.Array     # (P,) int32 — accepted updates per owner block
+    candidates: jax.Array    # () int32 — candidates of the accepted columns
 
 
 @partial(jax.jit, static_argnames=("Cn",))
+@jax.named_scope("route")
 def _route_window(cand, us, vs, ops_, valid, Cn: int) -> RouteMasks:
     """Device-side window routing: ONE fused kernel instead of the old host
     numpy pass (the O(N*R^2) `cand.T @ cand` overlap matmul, the spill
@@ -217,8 +222,9 @@ def _route_window(cand, us, vs, ops_, valid, Cn: int) -> RouteMasks:
     cand_del = jnp.any(candv & (accept & (ops_ < 0))[None, :], axis=1)
     per_block = jnp.zeros(N // Cn, jnp.int32).at[owner].add(
         accept.astype(jnp.int32))
+    candidates = jnp.sum(candv & accept[None, :], dtype=jnp.int32)
     return RouteMasks(accept, cross, esc_spill, esc_conflict,
-                      cand_ins, cand_del, per_block)
+                      cand_ins, cand_del, per_block, candidates)
 
 
 def _iter_windows(updates, R: int) -> Iterator[list]:
@@ -326,6 +332,7 @@ class StreamSession:
         """Windows ingested so far (the serving layer's staleness clock)."""
         return self._tot["batches"]
 
+    @tracing.span("stream.window")
     def apply_window(self, window: List[Tuple[int, int, int]]) -> None:
         """Ingest ONE window of at most R updates (see class docstring)."""
         if len(window) > self.R:
@@ -335,20 +342,22 @@ class StreamSession:
             return
         backend, W, tot = self.backend, self._W, self._tot
         window = [(self._cur(u), self._cur(v), op) for u, v, op in window]
-        while True:
-            try:
-                kd._validate_updates_host(self.g, window)
-                break
-            except CapacityError:
-                if not self._auto_grow:
-                    raise
-                # a row in this window is out of degree capacity: escalate
-                # Cd to the next pow2 and re-key the window ids (the grow
-                # relocates every row), then re-validate — one doubling
-                # almost always suffices (a window adds at most R edges).
-                rekey = self.grow(Cd=_pow2_ceil(self.g.Cd + 1))
-                window = [(int(rekey[u]), int(rekey[v]), op)
-                          for u, v, op in window]
+        with tracing.span("stream.validate"):
+            while True:
+                try:
+                    kd._validate_updates_host(self.g, window)
+                    break
+                except CapacityError:
+                    if not self._auto_grow:
+                        raise
+                    # a row in this window is out of degree capacity:
+                    # escalate Cd to the next pow2 and re-key the window
+                    # ids (the grow relocates every row), then re-validate
+                    # — one doubling almost always suffices (a window adds
+                    # at most R edges).
+                    rekey = self.grow(Cd=_pow2_ceil(self.g.Cd + 1))
+                    window = [(int(rekey[u]), int(rekey[v]), op)
+                              for u, v, op in window]
         g, core, ex, spmd = self.g, self.core, self.executor, self._spmd
         tot["batches"] += 1
         R = self.R
@@ -363,24 +372,27 @@ class StreamSession:
         valid = np.zeros(R, bool)
         valid[:n] = True
 
-        if spmd:
-            cand, steps = kd._batch_candidates_spmd(
-                ex, g, core, us, vs, valid)
-        else:
-            cand, steps = kd._batch_candidates(
-                g, core, jnp.asarray(us), jnp.asarray(vs),
-                jnp.asarray(valid), backend=backend)
+        with tracing.span("stream.candidates"):
+            if spmd:
+                cand, steps = kd._batch_candidates_spmd(
+                    ex, g, core, us, vs, valid)
+            else:
+                cand, steps = kd._batch_candidates(
+                    g, core, jnp.asarray(us), jnp.asarray(vs),
+                    jnp.asarray(valid), backend=backend)
 
         # routing on device: the (N, R) candidate matrix never reaches the
         # host — ONE transfer per window pulls the compact (R,)/(P,)
-        # verdict (bundled with the superstep counter).
-        route = _route_window(
-            jnp.asarray(cand), jnp.asarray(us), jnp.asarray(vs),
-            jnp.asarray(ops_), jnp.asarray(valid), Cn=g.Cn)
-        steps_h, accept, cross, spl, conf, nblk = jax.device_get(
-            (steps, route.accept, route.cross, route.spill, route.conflict,
-             route.per_block))
+        # verdict (bundled with the superstep and candidate counters).
+        with tracing.span("stream.route"):
+            route = _route_window(
+                jnp.asarray(cand), jnp.asarray(us), jnp.asarray(vs),
+                jnp.asarray(ops_), jnp.asarray(valid), Cn=g.Cn)
+            steps_h, accept, cross, spl, conf, nblk, ncand = jax.device_get(
+                (steps, route.accept, route.cross, route.spill,
+                 route.conflict, route.per_block, route.candidates))
         tot["bfs"] += int(steps_h)
+        tot["cand"] += int(ncand)
         self._esc_cross += int(cross.sum())
         self._esc_spill += int(spl.sum())
         self._esc_conflict += int(conf.sum())
@@ -391,15 +403,16 @@ class StreamSession:
             us_a = np.where(accept, us, 0).astype(np.int32)
             vs_a = np.where(accept, vs, 0).astype(np.int32)
             ops_a = np.where(accept, ops_, 0).astype(np.int32)
-            if spmd:
-                g, core, rec = kd._apply_and_recompute_spmd(
-                    g, core, us_a, vs_a, ops_a, route.cand_ins,
-                    route.cand_del, W=W, ex=ex)
-            else:
-                g, core, rec = kd._apply_and_recompute(
-                    g, core,
-                    jnp.asarray(us_a), jnp.asarray(vs_a), jnp.asarray(ops_a),
-                    route.cand_ins, route.cand_del, backend=backend)
+            with tracing.span("stream.apply"):
+                if spmd:
+                    g, core, rec = kd._apply_and_recompute_spmd(
+                        g, core, us_a, vs_a, ops_a, route.cand_ins,
+                        route.cand_del, W=W, ex=ex)
+                else:
+                    g, core, rec = kd._apply_and_recompute(
+                        g, core, jnp.asarray(us_a), jnp.asarray(vs_a),
+                        jnp.asarray(ops_a), route.cand_ins, route.cand_del,
+                        backend=backend)
             self._rec_dev = self._rec_dev + rec  # async; no host sync here
             self._n_local += int(accept.sum())
             self._per_block += nblk.astype(np.int64)
@@ -414,18 +427,19 @@ class StreamSession:
         # an executed node migration (a permutation, nothing recompiles)
         migrated_now = False
         if self._rebalance_threshold is not None:
-            if pd.block_balance(g) > self._rebalance_threshold:
-                moves = pd.choose_node_moves(
-                    g, max_moves=self._rebalance_max_moves,
-                    pair_counts=halo_pair_counts(g))
-                if moves:
-                    g, perm, core = migrate_vertices(g, moves, core)
-                    self._compose_perm(perm)
-                    self._migrations += 1
-                    self._migrated += len(moves)
-                    migrated_now = True
-                    if spmd:
-                        ex.rebuild(g)
+            with tracing.span("stream.rebalance"):
+                if pd.block_balance(g) > self._rebalance_threshold:
+                    moves = pd.choose_node_moves(
+                        g, max_moves=self._rebalance_max_moves,
+                        pair_counts=halo_pair_counts(g))
+                    if moves:
+                        g, perm, core = migrate_vertices(g, moves, core)
+                        self._compose_perm(perm)
+                        self._migrations += 1
+                        self._migrated += len(moves)
+                        migrated_now = True
+                        if spmd:
+                            ex.rebuild(g)
 
         # CC label maintenance: inserts only ever JOIN components, so an
         # insert-only window is an O(1)-superstep on-device label merge;
@@ -434,15 +448,16 @@ class StreamSession:
         # the post-window graph.
         if self._track_labels:
             ins_mask = valid & (ops_ > 0)
-            if (valid & (ops_ < 0)).any() or migrated_now:
-                self.labels = connected_components(g, backend=backend,
-                                                   executor=ex)
-                self._cc_recomputes += 1
-            elif ins_mask.any():
-                self.labels = merge_labels(
-                    self.labels, jnp.asarray(us), jnp.asarray(vs),
-                    jnp.asarray(ins_mask))
-                self._cc_merges += int(ins_mask.sum())
+            with tracing.span("stream.labels"):
+                if (valid & (ops_ < 0)).any() or migrated_now:
+                    self.labels = connected_components(g, backend=backend,
+                                                       executor=ex)
+                    self._cc_recomputes += 1
+                elif ins_mask.any():
+                    self.labels = merge_labels(
+                        self.labels, jnp.asarray(us), jnp.asarray(vs),
+                        jnp.asarray(ins_mask))
+                    self._cc_merges += int(ins_mask.sum())
         self.g, self.core = g, core
 
     # ---- elastic growth / recovery surface ------------------------------
@@ -674,6 +689,7 @@ class StreamSession:
             cc_merges=self._cc_merges,
             cc_recomputes=self._cc_recomputes,
             grows=self._grows,
+            candidates=self._tot["cand"],
         )
 
     def result(self) -> StreamResult:

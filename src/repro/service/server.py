@@ -27,6 +27,7 @@ from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Iterable, List, Optional, Tuple
 
+from .. import tracing
 from ..configs.service import ServiceConfig
 from ..runtime.stream import StreamResult, StreamSession, _iter_windows
 from . import queries as q
@@ -110,6 +111,7 @@ class QueryServer:
 
     # -- answering ---------------------------------------------------------
 
+    @tracing.span("service.query_batch")
     def _answer_batch(self, key: Tuple, batch: List[Request]) -> None:
         snap = self.state.snapshot
         kind = key[0]
@@ -152,6 +154,7 @@ class QueryServer:
 
     # -- the scheduling loop ----------------------------------------------
 
+    @tracing.span("service.step")
     def step(self, window: List[Tuple[int, int, int]]) -> int:
         """One serving turn: window -> cadenced refresh -> query batches.
 

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..core.algorithms import fused_analytics
 
 
@@ -124,6 +125,7 @@ class AnalyticsState:
         """Stream windows applied since the published snapshot was cut."""
         return self._session.windows_applied - self._front.windows
 
+    @tracing.span("service.refresh")
     def refresh(self) -> EpochSnapshot:
         """Cut + publish the next epoch's snapshot from the session head.
 
