@@ -71,6 +71,7 @@ BUCKET_HELPERS: FrozenSet[str] = frozenset({
     "_pad_to",
     "_tile_dims",
     "degree_bound",
+    "hybrid_split",
     "batch_bucket",
     "topk_bucket",
 })
@@ -130,10 +131,12 @@ HOST_BOUNDARIES: Dict[str, FrozenSet[str]] = {
         "_independent_prefix", "_spmd_executor",
     }),
     # backend resolution (platform query) + the sanctioned ONE-transfer
-    # sites: degree_bound (per fixpoint), run_block_program (n_real at
-    # entry), coreness_dense/coreness_blocks (bucketed K pull)
+    # sites: degree_bound and hybrid_split (per fixpoint),
+    # run_block_program (n_real at entry), coreness_dense/coreness_blocks
+    # (bucketed K pull)
     "repro/kernels/ops.py": frozenset({
-        "resolve_backend", "degree_bound", "run_block_program",
+        "resolve_backend", "degree_bound", "hybrid_split",
+        "run_block_program",
         "coreness_dense", "coreness_blocks", "dense_adj", "_pad_ell",
         "ell_lanes",
     }),

@@ -69,6 +69,12 @@ def ell_row_call(kernel, nbr, fields, fills, out_dtypes, T: int,
     kernel's VMEM blocks.  In a profile the gathers sit under the named
     scope ``gather`` and the kernel under `name`, its module's name.
 
+    The gather costs per slot, pads included, so `nbr` should be only as
+    wide as its rows need: the fused `ell` fixpoint calls this twice per
+    superstep, on a narrow head of every row and on a tail of the few
+    wide rows (`ops.hybrid_split`).  The fields are the gather's 1-D
+    tables and need not have `nbr`'s row count.
+
     Nodes are processed in chunks of `ROW_CHUNK` rows inside one
     `fori_loop`; the last chunk's start is clamped to N - rows, so it
     overlaps its predecessor and rewrites identical values.

@@ -66,9 +66,11 @@ def neighbor_multi_ell(
 ) -> Tuple[jax.Array, ...]:
     """Fused multi-field neighbor reduce over one shared ELL gather.
 
-    nbr: (N, Cd) int32 (-1 padded); fields: one (N,) vector per combine
-    (int32 for "min"/"hindex", float32 for "sum"); combines: static tuple
-    of names from `_FIELD_SPEC`.  Returns one (N,) reduction per field,
+    nbr: (N, Cd) int32 (-1 padded); fields: one 1-D table per combine
+    (int32 for "min"/"hindex", float32 for "sum"), usually (N,), but a
+    subset of rows (the tail of a hybrid ELL, `ops._multi_ell_hybrid`)
+    gathers from the whole graph's tables; combines: static tuple of
+    names from `_FIELD_SPEC`.  Returns one (N,) reduction per field,
     each bit-identical to its standalone kernel.  N % T == 0; Cd and K
     pass `check_cols` (pad via the ops.py wrapper).
     """
@@ -76,7 +78,7 @@ def neighbor_multi_ell(
     assert len(fields) == len(combines) >= 1, (len(fields), combines)
     for c, f in zip(combines, fields):
         assert c in _FIELD_SPEC, c
-        assert f.shape == (N,), (c, f.shape, N)
+        assert f.ndim == 1, (c, f.shape)
     assert N % T == 0, (N, T)
     check_cols(Cd, K)
     C = min(Cd, K)

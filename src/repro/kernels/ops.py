@@ -38,7 +38,10 @@ host transfers and returns its superstep count as a device scalar
 (`with_steps=True`).  The only host sync is the once-per-fixpoint
 `degree_bound` read that buckets the kernels' threshold/sort bound K to a
 power of two — the bucketing keeps the per-(shape, K) compiled caches
-hitting while the bound tracks the graph instead of the padded Cd.
+hitting while the bound tracks the graph instead of the padded Cd.  The
+program runner's `ell` fixpoint reads `hybrid_split` there instead: a
+pow2 head width every row gathers, plus a pow2 bucket of the few rows
+wider than it, gathered again whole.
 
 Beyond the two k-core primitives, the registry carries the named
 *neighbor combines* of the `BlockProgram` contract ("min" | "sum" |
@@ -198,6 +201,80 @@ def degree_bound(g) -> int:
     return min(Cdp, _pow2_bucket(max(1, d), floor=ELL_MIN_COLS))
 
 
+#: narrowest head of a hybrid ELL adjacency (`hybrid_split`); the kernels
+#: take any power of two below 128 lanes (`ell_hindex.check_cols`)
+HYBRID_MIN_COLS = 4
+
+
+class HybridSplit(NamedTuple):
+    """How a fixpoint's `ell` adjacency splits (see `hybrid_split`)."""
+
+    cols: int         # C: the full row width, `_ell_cols` of the widest row
+    head_cols: int    # W: the columns every row gathers
+    tail_rows: int    # rows wider than W, gathered again at width C
+    tail_padded: int  # their pow2 row bucket (floor one tile), 0 = no tail
+    slots: int        # slots gathered per field per superstep
+
+
+class HybridEll(NamedTuple):
+    """A fixpoint's `ell` adjacency operand (built by `_hybrid_ell`): the
+    first W columns of every padded row, plus the whole C-column rows of
+    the few rows wider than W and their row ids (N at pad entries).
+    Without a tail, `head` is the whole (Np, C) adjacency."""
+
+    head: jax.Array
+    tail: Optional[jax.Array] = None
+    rows: Optional[jax.Array] = None
+
+
+@jax.jit
+def _fill_widths(nbr: jax.Array):
+    """(rows wider than 2^k for each k below the padded Cd's bit length,
+    the widest row): the row fills of a left-filled adjacency, read as a
+    histogram by pow2 class, counted from the slots themselves."""
+    fill = jnp.sum(nbr >= 0, axis=1, dtype=jnp.int32)
+    Cdp = max(128, _pad_to(nbr.shape[1], 128))
+    widths = jnp.asarray([1 << k for k in range(Cdp.bit_length())],
+                         jnp.int32)
+    return jnp.sum(fill[:, None] > widths, axis=0), jnp.max(fill)
+
+
+def hybrid_split(nbr: jax.Array, T: int = 256) -> HybridSplit:
+    """Split the fused `ell` gather's adjacency into a head and a tail.
+
+    ONE host sync per call (read at the top of a fixpoint, never inside;
+    it stands in for `degree_bound` there).  The full width C is the
+    kernels' column bucket of the widest row, as `degree_bound` gives it
+    for left-filled rows; the head width W is the power of two in
+    [HYBRID_MIN_COLS, C] that gathers the fewest slots,
+    ``Np * W + tail_padded * C``, where the tail is every row wider than
+    W, bucketed to a power of two of at least one tile.  A head narrower
+    than C only comes with a tail, and ties keep W = C: a graph whose
+    rows share one width keeps the single (Np, C) adjacency.  Both W and
+    the tail bucket are powers of two, so maintenance streams keep
+    hitting the same compiled programs.  Under a jit trace this falls
+    back to the padded Cd and no tail, like `degree_bound`.
+    """
+    N, Cd = nbr.shape
+    Cdp = max(128, _pad_to(Cd, 128))
+    _, Np = _tile_dims(N, T)
+    if isinstance(nbr, jax.core.Tracer) or N == 0:
+        return HybridSplit(Cdp, Cdp, 0, 0, Np * Cdp)
+    wider, widest = jax.device_get(_fill_widths(nbr))
+    C = min(Cdp, _ell_cols(max(1, int(widest))))
+    best = HybridSplit(C, C, 0, 0, Np * C)
+    W = HYBRID_MIN_COLS
+    while W < C:
+        tail = int(wider[W.bit_length() - 1])
+        if tail:
+            tail_p = _pow2_bucket(tail, floor=T)
+            slots = Np * W + tail_p * C
+            if slots < best.slots:
+                best = HybridSplit(C, W, tail, tail_p, slots)
+        W *= 2
+    return best
+
+
 # ---------------------------------------------------------------------------
 # Dense-path wrappers (historical adjacency-matrix API, kept for the sweeps).
 # ---------------------------------------------------------------------------
@@ -339,6 +416,8 @@ def _pad_ell(nbr: jax.Array, K: Optional[int], T: int):
     (left-filled rows, see `degree_bound`) shrinks the columns the kernels
     gather and reduce to `_ell_cols(K)` (at most the padded Cd) — the
     pow2 bucketing upstream keeps Ck stable across maintenance streams.
+    The fused `ell` fixpoint pads through `_hybrid_ell`, which cuts the
+    padded rows further into a narrow head and a tail of wide rows.
     """
     N, Cd = nbr.shape
     Cdp = max(128, _pad_to(Cd, 128))
@@ -348,6 +427,26 @@ def _pad_ell(nbr: jax.Array, K: Optional[int], T: int):
     nbr_p = jnp.full((Np, Ck), -1, jnp.int32).at[:N, :Cc].set(
         nbr[:, :Cc].astype(jnp.int32))
     return nbr_p, Ck, Tp, Np
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("cols", "head_cols", "tail_padded", "T"))
+def _hybrid_ell(nbr: jax.Array, cols: int, head_cols: int, tail_padded: int,
+                T: int = 256) -> HybridEll:
+    """The `HybridEll` operand of a `hybrid_split` (its `cols`,
+    `head_cols`, `tail_padded`): `nbr` padded to tiles and `cols`
+    columns, its first `head_cols` columns as the head, and the rows
+    whose fill is wider than that as the tail, in row order.  Tail pad
+    entries hold no slots and the row id N, which names no output row."""
+    N = nbr.shape[0]
+    nbr_p = _pad_ell(nbr, cols, T)[0]
+    if not tail_padded:
+        return HybridEll(nbr_p)
+    wide = jnp.sum(nbr_p[:N] >= 0, axis=1) > head_cols
+    rows = jnp.nonzero(wide, size=tail_padded, fill_value=N)[0]
+    rows = rows.astype(jnp.int32)
+    tail = nbr_p.at[rows].get(mode="fill", fill_value=-1)
+    return HybridEll(nbr_p[:, :head_cols], tail, rows)
 
 
 def hindex_ell(
@@ -507,6 +606,21 @@ def _multi_ell_padded(nbr_p, N: int, fields, combines, interpret: bool,
     reds = _multi_ell_pallas(
         nbr_p, fields_p, combines, K=Ck, T=Tp, interpret=interpret)
     return tuple(r[:N] for r in reds)
+
+
+def _multi_ell_hybrid(adj: HybridEll, N: int, fields, combines,
+                      interpret: bool, T: int = 256) -> Tuple[jax.Array, ...]:
+    """`_multi_ell_padded` over a `HybridEll`: every row reduces its head
+    columns, then each tail row's output is overwritten by the reduction
+    of its whole row, so min, sum and hindex stay exact (tail pad entries
+    name row N and are dropped)."""
+    reds = _multi_ell_padded(adj.head, N, fields, combines, interpret, T)
+    if adj.tail is None:
+        return reds
+    tails = _multi_ell_pallas(adj.tail, tuple(fields), combines,
+                              K=adj.tail.shape[1], T=T, interpret=interpret)
+    return tuple(r.at[adj.rows].set(t, mode="drop")
+                 for r, t in zip(reds, tails))
 
 
 # ---------------------------------------------------------------------------
@@ -771,6 +885,17 @@ def gather_trace_count() -> int:
     return _GATHER_TRACES
 
 
+#: the `HybridSplit` of the last fused `ell` adjacency `run_block_program`
+#: built (None before the first): how far the head/tail split engages
+_LAST_SPLIT: Optional[HybridSplit] = None
+
+
+def last_hybrid_split() -> Optional[HybridSplit]:
+    """Head width, real and padded tail rows, and slots gathered per field
+    per superstep of the last fused `ell` adjacency (`_LAST_SPLIT`)."""
+    return _LAST_SPLIT
+
+
 def _combine_multi_jnp(nbr: jax.Array, fields, combines) -> Tuple:
     """Shared-gather multi reduce, pure jnp: one clip/validity, k takes."""
     valid = nbr >= 0
@@ -926,13 +1051,13 @@ def _block_program_fused(g, state0, adj, mirror, program, b: str,
             if b == "jnp":
                 return _combine_multi_jnp(g.nbr, field, program.combines)
             if b == "ell":
-                return _multi_ell_padded(adj, g.N, field, program.combines,
+                return _multi_ell_hybrid(adj, g.N, field, program.combines,
                                          interpret)
             return _combine_multi_dense(adj, field, program.combines, g.Cd)
         if b == "jnp":
             return _combine_jnp(g.nbr, field, program.combine)
         if b == "ell" and program.combine in MULTI_COMBINES:
-            return _multi_ell_padded(adj, g.N, (field,), (program.combine,),
+            return _multi_ell_hybrid(adj, g.N, (field,), (program.combine,),
                                      interpret)[0]
         if b == "ell":
             return _combine_ell(g.nbr, field, program.combine, interpret,
@@ -1007,6 +1132,13 @@ def run_block_program(
     own bound.  Returns the final program state, plus the executed
     superstep count when `with_steps=True`.
 
+    On `ell` the loop's adjacency is a `HybridEll`, built once outside
+    the loop after the one `hybrid_split` read: each superstep gathers
+    and reduces the head's W columns of every row, then the tail's whole
+    C-column rows, whose outputs replace those rows' head outputs.  The
+    split is read from the rows' fills, not from `g.deg`, so it holds for
+    a mirrored graph's slices too; `last_hybrid_split` reports it.
+
     `state0` (optional) warm-starts the fixpoint from a caller-supplied
     state instead of `program.init(g)` — the serving path's snapshot
     refresh uses this to resume monotone programs (min-label CC, min-H
@@ -1059,12 +1191,16 @@ def run_block_program(
         return (state, steps) if with_steps else state
     interpret = _interpret(interpret)
     # the loop's adjacency operand, built once outside it: the dense
-    # matrix, or the ELL lists padded to tiles and cut to the degree bound
+    # matrix, or the ELL lists padded to tiles and split into a narrow
+    # head and a tail of the few wide rows
     adj = None
     if b == "dense":
         adj = ref.ell_to_dense(g.nbr, g.N)
     elif b == "ell" and program.combine != "count_common":
-        adj = _pad_ell(g.nbr, degree_bound(g), 256)[0]
+        global _LAST_SPLIT
+        _LAST_SPLIT = split = hybrid_split(g.nbr)
+        adj = _hybrid_ell(g.nbr, split.cols, split.head_cols,
+                          split.tail_padded)
     state, steps = _block_program_fused(
         g, state0, adj, mirror, program=program, b=b, interpret=interpret,
         max_steps=ms, n_real=n_real)

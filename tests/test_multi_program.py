@@ -16,12 +16,16 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core import MultiProgram, build_ell_random, fused_analytics
+from repro.core import (
+    MultiProgram, build_blocks, build_ell_random, fused_analytics,
+)
 from repro.core.algorithms import (
     ConnectedComponentsProgram, CorenessBlockProgram, PageRankProgram,
     TriangleCountProgram, connected_components, pagerank,
 )
 from repro.kernels import ops
+
+from _road import road_graph
 
 STEPS = 30
 
@@ -54,10 +58,17 @@ def test_fused_runs_exactly_steps_supersteps(g):
     assert int(n) == STEPS  # fixed-iteration PageRank pins the loop length
 
 
+def _hybrid_adj(g):
+    """The `ell` loop operand `run_block_program` builds for `g`."""
+    split = ops.hybrid_split(g.nbr)
+    return ops._hybrid_ell(g.nbr, split.cols, split.head_cols,
+                           split.tail_padded)
+
+
 def _lower(g, program, b):
     """Force a fresh trace of the fused superstep loop (no jit cache)."""
     state0 = program.init(g)
-    adj = ops._pad_ell(g.nbr, None, 256)[0] if b == "ell" else None
+    adj = _hybrid_adj(g) if b == "ell" else None
     ops._block_program_fused.lower(
         g, state0, adj, None, program=program, b=b, interpret=True,
         max_steps=5, n_real=int(g.n_real))
@@ -72,6 +83,16 @@ def test_fused_traces_one_gather_where_standalone_trace_three(g, b):
     for p in _programs():
         _lower(g, p, b)
     assert ops.gather_trace_count() - before == 3
+
+
+@pytest.mark.parametrize("b", ["jnp", "ell"])
+def test_hybrid_fused_traces_one_gather(b):
+    """A head and a tail are still ONE gather dispatch per superstep."""
+    road = road_graph(768, 4, 12, Cn=384)
+    assert b == "jnp" or _hybrid_adj(road).tail is not None
+    before = ops.gather_trace_count()
+    _lower(road, MultiProgram(_programs(), max_steps=5), b)
+    assert ops.gather_trace_count() - before == 1
 
 
 def test_multi_kernel_direct_parity(g):
@@ -128,3 +149,78 @@ def test_auto_crossover_table(monkeypatch):
     # explicit names pass through untouched on every platform
     for b in ("jnp", "dense", "ell", "ell_spmd"):
         assert ops.resolve_backend(b, 17) == b
+
+
+# ---------------------------------------------------------------------------
+# hybrid ELL: every row gathers a narrow head, the few wide rows a tail
+# ---------------------------------------------------------------------------
+
+
+#: (n, hubs, hub width, Cn) -> the expected split: full width C, head W,
+#: real and padded tail rows, and slots per field per superstep
+HYBRID_CASES = {
+    # no row wider than 4: a 4-column head would have no tail, so C = 8
+    "tail0": ((700, 0, 12, 350), (8, 8, 0, 0, 768 * 8)),
+    # one hub; N = 700 is not a multiple of the 256-row tile
+    "tail1": ((700, 1, 12, 350), (16, 4, 1, 256, 768 * 4 + 256 * 16)),
+    # roadNet-CA's shape; N = 768 fills its tiles, so row N - 1 is real
+    # and the tail's pad entries (row id N) must write nowhere
+    "road_aligned": ((768, 4, 12, 384), (16, 4, 4, 256, 768 * 4 + 256 * 16)),
+    # 257 wide rows: just over one tail bucket
+    "tail257": ((2000, 257, 6, 1000), (8, 4, 257, 512, 2048 * 4 + 512 * 8)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HYBRID_CASES))
+def test_hybrid_fused_matches_jnp(case):
+    (n, hubs, width, Cn), want = HYBRID_CASES[case]
+    road = road_graph(n, hubs, width, Cn=Cn)
+    assert road.N == 2 * Cn
+    core, lab, rank = fused_analytics(road, steps=STEPS, backend="ell")
+    assert ops.last_hybrid_split() == ops.HybridSplit(*want)
+    core_ref, lab_ref, rank_ref = fused_analytics(road, steps=STEPS,
+                                                  backend="jnp")
+    np.testing.assert_array_equal(np.asarray(core), np.asarray(core_ref))
+    np.testing.assert_array_equal(np.asarray(lab), np.asarray(lab_ref))
+    np.testing.assert_allclose(np.asarray(rank), np.asarray(rank_ref),
+                               rtol=1e-6, atol=0)
+
+
+def test_hybrid_regular_graph_keeps_one_adjacency():
+    """Rows of one width: W = C and no tail, today's (Np, C) operand."""
+    n = 512
+    u = np.arange(n)
+    edges = np.concatenate([np.stack([u, (u + k) % n], 1) for k in (1, 2, 3)])
+    reg = build_blocks(edges, n, u % 2, P=2)
+    split = ops.hybrid_split(reg.nbr)
+    assert split == ops.HybridSplit(8, 8, 0, 0, 512 * 8)
+    adj = _hybrid_adj(reg)
+    assert adj.tail is None and adj.rows is None
+    assert adj.head.shape == (512, 8)
+    np.testing.assert_array_equal(
+        np.asarray(adj.head), np.asarray(ops._pad_ell(reg.nbr, 6, 256)[0]))
+    core, lab, rank = fused_analytics(reg, steps=STEPS, backend="ell")
+    assert ops.last_hybrid_split() == split
+    core_ref, lab_ref, rank_ref = fused_analytics(reg, steps=STEPS,
+                                                  backend="jnp")
+    np.testing.assert_array_equal(np.asarray(core), np.asarray(core_ref))
+    np.testing.assert_array_equal(np.asarray(lab), np.asarray(lab_ref))
+    np.testing.assert_allclose(np.asarray(rank), np.asarray(rank_ref),
+                               rtol=1e-6, atol=0)
+
+
+def test_hybrid_operand_rows():
+    """The tail holds the wide rows whole, in row order; its pad entries
+    hold no slots and name row N."""
+    road = road_graph(768, 4, 12, Cn=384)
+    adj = _hybrid_adj(road)
+    nbr = np.asarray(road.nbr)
+    wide = np.flatnonzero((nbr >= 0).sum(1) > 4)
+    assert len(wide) == 4
+    rows = np.asarray(adj.rows)
+    np.testing.assert_array_equal(rows[:4], wide)
+    assert (rows[4:] == road.N).all()
+    tail = np.asarray(adj.tail)
+    np.testing.assert_array_equal(tail[:4, :12], nbr[wide, :12])
+    assert (tail[:4, 12:] == -1).all() and (tail[4:] == -1).all()
+    np.testing.assert_array_equal(np.asarray(adj.head), nbr[:, :4])

@@ -3,10 +3,12 @@
 Every Pallas kernel that `ops.resolve_backend` can route to on a TPU is
 lowered and compiled with `interpret=False` for a described `v5e:2x2`
 topology at the widths of the roadNet-CA full-scale deployment that
-`chip_smoke.py` runs (N = 1,965,248 nodes padded to 256-row tiles; the
-max degree 6 gathers an 8-slot column bucket, the 128-lane kernels a
-whole lane chunk, and a 256-lane row stands for a wider graph), plus the
-smoke's whole fused static pass and one jitted `ell_spmd` superstep on a
+`chip_smoke.py` runs (N = 1,965,248 nodes padded to 256-row tiles; an
+8-slot column bucket stands for a low-degree graph, the 128-lane kernels
+take a whole lane chunk, and a 256-lane row stands for a wider graph),
+plus the smoke's whole fused static pass over its hybrid adjacency (a
+4-column head, one tail bucket of 16-column rows) and one jitted
+`ell_spmd` superstep on a
 4-device mesh built from the described devices.  Nothing runs: the TPU compiler
 refuses here what it would refuse on the chip (unsupported primitives,
 misaligned tiles, fast-memory overflow), at no chip time.
@@ -31,7 +33,7 @@ from repro.core.graph import GraphBlocks
 from repro.kernels import ops
 from repro.kernels.ell_cc import neighbor_min_ell
 from repro.kernels.ell_frontier import frontier_step_ell
-from repro.kernels.ell_hindex import hindex_ell
+from repro.kernels.ell_hindex import ROW_CHUNK, hindex_ell
 from repro.kernels.ell_multi import neighbor_multi_ell
 from repro.kernels.ell_pagerank import neighbor_sum_ell
 from repro.kernels.ell_triangles import neighbor_common_ell
@@ -42,11 +44,15 @@ from repro.runtime import spmd
 from repro.runtime.mesh import AXIS
 
 #: roadNet-CA at scale 1.0, 8 random blocks: P * Cn rows (N once padded to
-#: the 256-row tile); Cd = max degree 6 + deg_slack 64, of which the
-#: kernels gather the 8-column bucket C8; C = a 256-lane row; R = the
+#: the 256-row tile); Cd = max degree 12 + deg_slack 64; C8 = an 8-column
+#: bucket (a graph of max degree 5-8); C = a 256-lane row; R = the
 #: stream's window
-P, CN, CD = 8, 245_656, 70
+P, CN, CD = 8, 245_656, 76
 N, C, C8, T, R = 1_965_312, 256, 8, 256, 8
+#: the fused pass's hybrid adjacency at roadNet-CA's widths: a 4-column
+#: head for every row, and its 17 rows wider than 4 whole (the 16-column
+#: bucket of max degree 12) in one tail bucket of a tile
+W4, C16, TAIL = 4, 16, 256
 #: HBM of one TPU v5e chip
 HBM_BYTES = 16 * 10**9
 #: the dense backend's largest graph under "auto"
@@ -144,7 +150,8 @@ def test_spmd_superstep_compiles_on_4_chip_mesh(topo):
 
 def test_static_fused_pass_compiles_and_fits(shape):
     """The smoke's static phase as one program: the fused coreness + CC +
-    PageRank `while_loop` on the ELL kernels at full scale, within HBM."""
+    PageRank `while_loop` on the ELL kernels at full scale, within HBM,
+    over the hybrid adjacency `ops.hybrid_split` picks for roadNet-CA."""
     n = P * CN
     g = GraphBlocks(nbr=shape((n, CD), jnp.int32), deg=shape((n,), jnp.int32),
                     node_mask=shape((n,), jnp.bool_),
@@ -155,7 +162,9 @@ def test_static_fused_pass_compiles_and_fits(shape):
          PageRankProgram(tol=None, max_steps=steps)), max_steps=steps)
     vec = shape((n,), jnp.int32)
     state0 = (vec, vec, (shape((n,), jnp.float32), shape((n,), jnp.float32)))
-    adj = shape((N, C8), jnp.int32)  # padded once, cut to the degree bound
+    adj = ops.HybridEll(shape((N, W4), jnp.int32),
+                        shape((TAIL, C16), jnp.int32),
+                        shape((TAIL,), jnp.int32))
     compiled = ops._block_program_fused.lower(
         g, state0, adj, None, program=prog, b="ell", interpret=False,
         max_steps=steps, n_real=n).compile()
@@ -164,12 +173,16 @@ def test_static_fused_pass_compiles_and_fits(shape):
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES
-    # every field's gather reads its (N,) table from VMEM (memory space
-    # S(1)); side by side XLA staged only one and the others read HBM
-    spaces = []
+    # every field's head and tail gather reads its (N,) table from VMEM
+    # (memory space S(1)); side by side XLA staged only one and the others
+    # read HBM.  The head gathers a row chunk's W4 columns, the tail its
+    # whole rows
+    gathers = []
     for comp in text.split("\n\n"):
-        m = re.search(r" gather\(%(\S+), ", comp)
+        m = re.search(r" = \w+\[([\d,]+)\]\S* gather\(%(\S+), ", comp)
         if m:
-            decl = re.search("%" + re.escape(m.group(1)) + r" = (\S+) ", comp)
-            spaces.append("S(1)" in decl.group(1))
-    assert spaces == [True] * 3, spaces
+            decl = re.search("%" + re.escape(m.group(2)) + r" = (\S+) ", comp)
+            gathers.append(("S(1)" in decl.group(1), m.group(1)))
+    chunk = ROW_CHUNK // T * T
+    assert sorted(gathers) == sorted(
+        [(True, f"{chunk},{W4}")] * 3 + [(True, f"{TAIL},{C16}")] * 3), gathers

@@ -29,6 +29,8 @@ from repro.graphgen import barabasi_albert
 from repro.kernels import ops, ref
 from repro.runtime import run_stream
 
+from _road import road_graph
+
 ALL_BACKENDS = ("jnp", "dense", "ell", "ell_spmd")
 
 
@@ -303,6 +305,17 @@ def test_workload_fixpoints_transfer_count_is_o1(count_device_get):
         assert count_device_get["n"] == 0, (b, count_device_get["n"])
 
 
+def test_workload_fixpoints_transfer_count_is_o1_with_tail(
+        count_device_get):
+    """The hybrid split is read in the same one transfer per fixpoint."""
+    g = road_graph(768, 4, 12, Cn=384)
+    count_device_get["n"] = 0
+    _, steps = connected_components(g, backend="ell", with_steps=True)
+    assert count_device_get["n"] == 1, count_device_get["n"]
+    assert ops.last_hybrid_split().tail_rows == 4
+    assert int(steps) > 10  # many supersteps, still one transfer
+
+
 def test_workload_fixpoint_spmd_one_transfer_per_run(count_device_get):
     g = _path_graph(64, P=2)
     count_device_get["n"] = 0
@@ -311,6 +324,46 @@ def test_workload_fixpoint_spmd_one_transfer_per_run(count_device_get):
     # ONE device_get per run (the fused loop's superstep count), never
     # one per superstep
     assert count_device_get["n"] <= 2, (count_device_get["n"], int(steps))
+
+
+# ---------------------------------------------------------------------------
+# the hybrid ELL (a narrow head for every row, a tail of the wide rows)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n,hubs,Cn", [(700, 1, 350), (768, 4, 384)])
+def test_hybrid_workloads_match_oracles(n, hubs, Cn):
+    """Standalone CC, coreness and PageRank on a split `ell` adjacency."""
+    g = road_graph(n, hubs, 12, Cn=Cn)
+    labels = connected_components(g, backend="ell")
+    split = ops.last_hybrid_split()
+    assert (split.head_cols, split.tail_rows) == (4, hubs)
+    np.testing.assert_array_equal(np.asarray(labels), _cc_ref(g))
+    np.testing.assert_array_equal(
+        np.asarray(ops.run_block_program(g, CorenessBlockProgram(),
+                                         backend="ell")),
+        np.asarray(coreness(g, backend="jnp")))
+    np.testing.assert_allclose(
+        np.asarray(pagerank(g, tol=1e-8, max_steps=500, backend="ell")),
+        _pagerank_ref(g), atol=2e-6)
+
+
+def test_hybrid_mirrored_hub_split():
+    """A hub-split graph's slices pick their own split from the rows'
+    fills; the mirrored `ell` run equals the mirrored jnp run."""
+    from repro.core.algorithms import fused_analytics
+    from repro.core.hub_split import split_hubs
+
+    g2, plan = split_hubs(road_graph(700, 4, 12, node_slack=32),
+                          threshold=8)
+    got = fused_analytics(g2, steps=30, backend="ell", mirror=plan)
+    split = ops.last_hybrid_split()
+    assert split.cols == 8 and split.head_cols == 4 and split.tail_rows > 0
+    want = fused_analytics(g2, steps=30, backend="jnp", mirror=plan)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_allclose(np.asarray(got[2]), np.asarray(want[2]),
+                               rtol=1e-6, atol=0)
 
 
 # ---------------------------------------------------------------------------
