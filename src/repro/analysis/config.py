@@ -155,6 +155,8 @@ HOST_BOUNDARIES: Dict[str, FrozenSet[str]] = {
         # capacity escalation: full plan rebuild at the new (Cn, Cd),
         # same boundary as rebuild
         "grow", "refresh_fields",
+        # a hub-split plan's tables laid out per worker, once per plan
+        "mirror_tables",
     }),
     # stream host driver: window padding (np), the ONE bundled verdict
     # pull per window, and host routing arithmetic; _route_window and
@@ -223,6 +225,9 @@ SORTED_ELL_WRITERS: FrozenSet[str] = SORTED_ELL_HELPERS | frozenset({
     # from the value-flow check)
     "split_hubs",
     "apply_mirrored_edits",
+    # the split rule's assembly writes each row's slots from one sorted
+    # (row, neighbour) key array, so rows arrive ascending, pads right
+    "_assemble",
     "run_common_mirror",
     # grow_blocks value-remaps nbr through a MONOTONE rekey (row slots
     # keep their relative order, pads stay right-justified), so the

@@ -126,24 +126,22 @@ def _relabel(
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Map original ids -> block-contiguous padded ids.
 
-    Returns (new_of_old (n,), old_of_new (P*Cn,)).
+    Block ``b``'s nodes take the rows ``b*Cn + r`` in increasing
+    original id.  Returns (new_of_old (n,), old_of_new (P*Cn,)).
     """
-    new_of_old = np.full(n, PAD, dtype=np.int64)
-    old_of_new = np.full(P * Cn, PAD, dtype=np.int64)
-    counts = np.zeros(P, dtype=np.int64)
+    pop = np.bincount(assign, minlength=P)
+    if pop.size and pop.max() > Cn:
+        b = int(np.argmax(pop))
+        raise ValueError(
+            f"block {b} overflows node capacity Cn={Cn} "
+            f"(needs at least {int(pop[b])})")
     order = np.argsort(assign, kind="stable")
-    for old in order:
-        b = assign[old]
-        slot = counts[b]
-        if slot >= Cn:
-            raise ValueError(
-                f"block {b} overflows node capacity Cn={Cn} "
-                f"(needs at least {np.sum(assign == b)})"
-            )
-        new = b * Cn + slot
-        new_of_old[old] = new
-        old_of_new[new] = old
-        counts[b] += 1
+    blocks = assign[order]
+    starts = np.concatenate([[0], np.cumsum(pop)[:-1]])
+    new_of_old = np.empty(n, dtype=np.int64)
+    new_of_old[order] = blocks * Cn + (np.arange(n) - starts[blocks])
+    old_of_new = np.full(P * Cn, PAD, dtype=np.int64)
+    old_of_new[new_of_old] = np.arange(n)
     return new_of_old, old_of_new
 
 

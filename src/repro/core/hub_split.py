@@ -23,8 +23,8 @@ analysis in PAPERS.md) *without* changing the block-centric runtime:
     degree.  `kernels.ops.run_block_program(..., mirror=plan)` inserts a
     **combine-then-broadcast merge** between the neighbor combine and
     `BlockProgram.update`: per-slice partial aggregates are merged per
-    group (min/sum exactly associative; hindex via count-histogram
-    partials, the threshold-count formulation) and the merged value
+    group (min/sum exactly associative; hindex by bisection on the
+    threshold, whose counts add exactly across slices) and the merged value
     is written back to every group row.  Because program state is
     replicated onto mirror rows (`BlockProgram.mirror_state`), replicas
     advance in lockstep with their primary and every *reader* of a
@@ -49,19 +49,20 @@ corrections are numpy preprocessing, same contract as `build_blocks` /
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from .graph import (PAD, CapacityError, GraphBlocks, _occurrence_ranks,
-                    halo_slot_counts, relocate_rows, sort_nbr_rows)
+                    _relabel, halo_slot_counts, relocate_rows,
+                    sort_nbr_rows)
 
-#: monotonic MirrorPlan identity counter — the SPMD fused loop closes over
-#: the plan arrays (they are compile-time constants of the shard_map'd
-#: step), so every plan with distinct array *content* must carry a distinct
-#: `uid` for the compiled-step caches to key on (see CACHE_SCHEMAS).
+#: monotonic MirrorPlan identity counter: every plan with distinct array
+#: *content* carries a distinct `uid`, which keys the per-executor mesh
+#: layout of its tables (`runtime.spmd.SpmdExecutor.mirror_tables`).
 _UID_COUNTER = [0]
 
 
@@ -97,7 +98,7 @@ class MirrorPlan:
     grp_gid:      (Rp,) int32 — group id per entry; Gmax on padding.
     row_gid:      (N,) int32 — group id of each row; Gmax off-group.
     Gmax, Km:     static ints — pow2-bucketed group count / max logical
-                  hub degree (the hindex histogram width; exact because
+                  hub degree (the bound the hindex merge bisects below;
                   a merged h-index never exceeds the logical degree).
     threshold:    static int — the split threshold == per-slice capacity.
     n_logical:    static int — real *logical* vertex count (what
@@ -203,112 +204,258 @@ def split_hubs(g: GraphBlocks, threshold: int) -> Tuple[GraphBlocks,
     of at most `threshold` neighbors lands in an existing padding row —
     preferentially in the block its slice members live in, so slice
     reads stay block-local.  Non-hub rows are byte-identical up to the
-    column truncation.  Raises when a block runs out of padding rows
-    (build with `build_blocks(node_slack=...)` headroom).
+    column truncation.  Raises when the padding rows run out (build with
+    `build_blocks(node_slack=...)` headroom, or load the split graph
+    straight from its edges with `build_split_blocks`, which sizes the
+    padding itself).  The split rule is `_split_rule`'s, shared with
+    `build_split_blocks`.
 
     Both endpoint sides of an edge re-point at the serving row of the
     other side, so ``g2`` is a consistent undirected ELL graph and every
-    row obeys the sorted-ELL invariant (established by `sort_nbr_rows`).
-    Host-side preprocessing; raises under a trace.
+    row obeys the sorted-ELL invariant.  Host-side preprocessing; raises
+    under a trace.
     """
     if isinstance(g.nbr, jax.core.Tracer):
         raise TypeError("split_hubs is host-side preprocessing; it cannot "
                         "run under jit/vmap tracing.")
+    t = _check_threshold(threshold)
+    nbr = np.asarray(g.nbr, np.int64)
+    deg = np.asarray(g.deg, np.int64)
+    mask = np.asarray(g.node_mask)
+    rows, cols = np.nonzero(nbr >= 0)
+    vals = nbr[rows, cols]
+    up = rows < vals  # each undirected edge once, as (lo, hi)
+    free = np.flatnonzero(~mask)
+    split = _split_rule(rows[up], vals[up], np.where(mask, deg, 0), t, g.Cn,
+                        g.P, free)
+    return _assemble(split, mask, np.asarray(g.orig_id, np.int64), deg,
+                     g.P, g.Cn, t, n_logical=int(mask.sum()))
+
+
+def _check_threshold(threshold: int) -> int:
     t = int(threshold)
     if t < 1:
         raise ValueError(f"threshold must be >= 1, got {t}")
-    nbr = np.asarray(g.nbr, np.int64)
-    deg = np.asarray(g.deg, np.int64)
-    mask = np.asarray(g.node_mask).copy()
-    orig = np.asarray(g.orig_id, np.int64).copy()
-    N, Cn, Cd = g.N, g.Cn, g.Cd
+    return t
 
-    hubs = np.flatnonzero(mask & (deg > t))
-    free = _free_rows(mask, Cn, g.P)
 
-    # serving-row maps, per directed slot of the ORIGINAL graph:
-    #   rew[u, j]  — the row that holds u's slot j after the split
-    #   rew2[u, j] — the row the slot's content re-points to (the partner
-    #                endpoint's serving row for this edge)
-    rew = np.repeat(np.arange(N, dtype=np.int64), Cd).reshape(N, Cd)
-    rew2 = nbr.copy()
-    groups: List[Tuple[int, List[int]]] = []
-    for h in hubs:
-        d = int(deg[h])
-        nb = nbr[h, :d]  # sorted (ELL invariant)
-        own = h // Cn
-        blk = nb // Cn
-        # own-block members first, then grouped by reader block: consecutive
-        # chunks of <= t then cut along block boundaries where possible
-        order = np.lexsort((nb, np.where(blk == own, -1, blk)))
-        nb_o = nb[order]
-        n_chunks = -(-d // t)
-        rows_h = [int(h)]
-        for ci in range(1, n_chunks):
-            chunk = nb_o[ci * t:(ci + 1) * t]
-            r = _alloc_replica(free, int(chunk[0] // Cn), int(own))
-            rows_h.append(r)
-            mask[r] = True
-            orig[r] = orig[h]
-        groups.append((int(h), rows_h))
-        for ci, r in enumerate(rows_h):
-            chunk = nb_o[ci * t:(ci + 1) * t]
-            # u-side: these slots are served by row r
-            rew[h, np.searchsorted(nb, chunk)] = r
-            # partner side: w's slot pointing at h re-points to r
-            for w in chunk:
-                pos = np.searchsorted(nbr[w, :deg[w]], h)
-                rew2[w, pos] = r
+class _Split(NamedTuple):
+    """What `_split_rule` decides, in padded row ids."""
 
-    valid = nbr >= 0
-    src = rew[valid]
-    dst = rew2[valid]
-    nbr2 = np.full((N, t), PAD, np.int64)
-    ranks = _occurrence_ranks(src)
-    if ranks.size and ranks.max() >= t:
-        raise AssertionError("slice overflow — split_hubs chunking bug")
-    nbr2[src, ranks] = dst
+    slots: np.ndarray     # (2m,) serving row * N + partner's serving row
+    replicas: np.ndarray  # (R,) replica rows, in request order
+    req_hub: np.ndarray   # (R,) the hub each replica serves
+    req_chunk: np.ndarray  # (R,) its slice number (1, 2, ...)
+    hubs: np.ndarray      # (H,) hub rows, ascending
+    N: int
+
+
+def _hub_chunks(lo, hi, deg, t: int, Cn: int):
+    """Each hub's slots in slice order, cut into slices of ``t``.
+
+    A hub's neighbors are ordered own-block first, then by reader block,
+    then by id, and consecutive runs of ``t`` form its slices.  Returns
+    (directed slot index of each hub slot in that order, its slice
+    number, and per replica request — slice 1, 2, ... of each hub in
+    ascending hub order — its hub and its preferred block, the block of
+    the slice's first member)."""
+    src = np.concatenate([lo, hi])
+    hs = np.flatnonzero(deg[src] > t)
+    s, d = src[hs], np.concatenate([hi, lo])[hs]
+    blk = d // Cn
+    near = np.where(blk == s // Cn, 0, blk + 1)
+    span = np.int64(max(int(d.max()) + 1, 1) if d.size else 1)
+    order = np.argsort((s * (blk.max(initial=0) + 2) + near) * span + d)
+    hs, s, d = hs[order], s[order], d[order]
+    del order, blk, near
+    chunk = _group_ranks(s) // t
+    head = np.flatnonzero(np.r_[False, chunk[1:] != chunk[:-1]]
+                          & (chunk > 0))
+    return hs, chunk, s[head], chunk[head], d[head] // Cn
+
+
+def _group_ranks(keys: np.ndarray) -> np.ndarray:
+    """Position of each entry within its run of equal sorted ``keys``."""
+    if not keys.size:
+        return np.zeros(0, np.int64)
+    first = np.flatnonzero(np.r_[True, keys[1:] != keys[:-1]])
+    return np.arange(len(keys)) - np.repeat(
+        first, np.diff(np.r_[first, len(keys)]))
+
+
+def _allocate(req_hub, req_pref, Cn: int, P: int,
+              free: np.ndarray) -> np.ndarray:
+    """A padding row for each replica request, in request order: the
+    lowest free row of the preferred block, else of the hub's own block,
+    else of the lowest block with one (`_alloc_replica`).  When every
+    block can serve its preferred requests, that sequential rule is a
+    rank within the block, so only a block that runs dry makes it walk
+    the requests one by one."""
+    free = np.asarray(free, np.int64)
+    start = np.searchsorted(free, np.arange(P + 1, dtype=np.int64) * Cn)
+    want = np.bincount(req_pref, minlength=P)
+    if (want <= np.diff(start)).all():
+        return free[start[req_pref] + _occurrence_ranks(req_pref)]
+    pools = {b: list(free[start[b]:start[b + 1]]) for b in range(P)}
+    return np.array([_alloc_replica(pools, int(p), int(h) // Cn)
+                     for h, p in zip(req_hub, req_pref)], np.int64)
+
+
+def _split_rule(lo, hi, deg, t: int, Cn: int, P: int, free,
+                sized=None) -> _Split:
+    """The one hub-split rule, on an edge list in padded row ids.
+
+    ``lo``/``hi`` hold each undirected edge once; ``deg`` is each row's
+    degree, 0 on padding; ``free`` the ascending padding rows replicas
+    may take.  ``sized(req_pref)``, when given, is called with each
+    request's preferred block before any row is allocated and returns
+    ``(Cn, remap, free)`` for a node axis laid out anew (the loader
+    sizes its padding from the requests); ``remap`` carries row ids
+    into it and must keep their order.
+    """
+    hs, chunk, req_hub, req_chunk, req_pref = _hub_chunks(lo, hi, deg, t, Cn)
+    hubs = np.flatnonzero(deg > t)
+    N = P * Cn
+    if sized is not None:
+        Cn, remap, free = sized(req_pref)
+        lo, hi, hubs, req_hub = (remap(x) for x in (lo, hi, hubs, req_hub))
+        N = P * Cn
+    replicas = _allocate(req_hub, req_pref, Cn, P, free)
+    del req_pref
+    m = len(lo)
+    serve = np.concatenate([lo, hi])
+    hub_of = serve[hs]
+    more = chunk > 0
+    first_req = np.searchsorted(req_hub, hub_of[more])
+    hub_of[more] = replicas[first_req + chunk[more] - 1]
+    serve[hs] = hub_of
+    del hub_of, hs, chunk, first_req, more
+    # the slot (u -> v) sits in u's serving row and points at v's serving
+    # row for this edge, which is the serving row of the slot (v -> u)
+    slots = serve * np.int64(N) + np.concatenate([serve[m:], serve[:m]])
+    del serve
+    slots.sort()
+    return _Split(slots, replicas, req_hub, req_chunk, hubs, N)
+
+
+def _assemble(split: _Split, mask, orig, deg_logical, P: int, Cn: int,
+              t: int, n_logical: int) -> Tuple[GraphBlocks, MirrorPlan]:
+    """The split graph and its plan from `_split_rule`'s decisions.
+
+    ``mask``, ``orig`` and ``deg_logical`` are per row of the node axis
+    the split was allocated in, before the replicas (the unsplit degree
+    of each row's vertex)."""
+    N = split.N
+    src, dst = np.divmod(split.slots, np.int64(N))
+    rank = _group_ranks(src)
+    if rank.size and rank.max() >= t:
+        raise AssertionError("slice overflow — hub split chunking bug")
+    nbr2 = np.full((N, t), PAD, np.int32)
+    nbr2[src, rank] = dst  # slots arrive sorted: rows ascend, pads right
     deg2 = np.bincount(src, minlength=N)
-    nbr2 = sort_nbr_rows(nbr2)  # establish the sorted-ELL invariant
-
+    del src, dst, rank
+    mask = np.asarray(mask).copy()
+    orig = np.asarray(orig, np.int64).copy()
+    mask[split.replicas] = True
+    orig[split.replicas] = orig[split.req_hub]
     g2 = GraphBlocks(
-        nbr=jnp.asarray(nbr2, jnp.int32),
-        deg=jnp.asarray(deg2, jnp.int32),
-        node_mask=jnp.asarray(mask),
-        orig_id=jnp.asarray(orig, jnp.int32),
-        P=g.P, Cn=Cn, Cd=t,
-    )
-    plan = _plan_from_groups(
-        N=N, deg_logical_of_row=deg, mask=mask,
-        groups={h: rs for h, rs in groups}, threshold=t,
-        n_logical=int(np.asarray(g.node_mask).sum()))
+        nbr=jnp.asarray(nbr2), deg=jnp.asarray(deg2, jnp.int32),
+        node_mask=jnp.asarray(mask), orig_id=jnp.asarray(orig, jnp.int32),
+        P=P, Cn=Cn, Cd=t)
+    # groups: each hub's primary row first, then its replicas by slice
+    prim = np.concatenate([split.hubs, split.req_hub])
+    rows = np.concatenate([split.hubs, split.replicas])
+    order = np.lexsort((np.concatenate([np.zeros(len(split.hubs), np.int64),
+                                        split.req_chunk]), prim))
+    plan = _plan_arrays(N, deg_logical, mask, prim[order], rows[order],
+                        threshold=t, n_logical=n_logical)
     return g2, plan
+
+
+def build_split_blocks(edges: np.ndarray, n: int, assign: np.ndarray,
+                       P: int, threshold: int
+                       ) -> Tuple[GraphBlocks, MirrorPlan]:
+    """Load a hub-split graph straight from its edge list.
+
+    The same graph and plan as ``split_hubs(build_blocks(edges, n,
+    assign, P, Cn=Cn), threshold)``, by the same split rule, without the
+    unsplit graph's (N, max degree) array: host memory stays O(|E|) and
+    no step loops over hubs in Python.  ``Cn`` is the least multiple of
+    8 that holds every block's nodes and the replicas that prefer it.
+    Nodes take block ``b``'s rows ``b*Cn + r`` in increasing original
+    id, as `build_blocks` lays them out; each block's replicas take the
+    rows after its nodes.  Host-side preprocessing.
+    """
+    with tracing.span("graph.split_load"):
+        t = _check_threshold(threshold)
+        assign = np.asarray(assign, np.int64)
+        assert assign.shape == (n,), (assign.shape, n)
+        e = np.asarray(edges, np.int64).reshape(-1, 2)
+        lo, hi = np.minimum(e[:, 0], e[:, 1]), np.maximum(e[:, 0], e[:, 1])
+        keep = lo != hi
+        key = np.unique(lo[keep] * np.int64(max(n, 1)) + hi[keep])
+        del e, lo, hi, keep
+        lo0, hi0 = np.divmod(key, np.int64(max(n, 1)))
+        del key
+        deg0 = np.bincount(lo0, minlength=n) + np.bincount(hi0, minlength=n)
+        pop = np.bincount(assign, minlength=P)
+        C0 = max(1, int(pop.max()))
+        new0, _ = _relabel(n, assign, P, C0)
+        deg = np.zeros(P * C0, np.int64)
+        deg[new0] = deg0
+
+        def sized(req_pref):
+            need = pop + np.bincount(req_pref, minlength=P)
+            C = int(-(-max(1, int(need.max())) // 8) * 8)
+            free = (np.arange(P)[:, None] * C
+                    + np.arange(C)[None, :])[np.arange(C)[None, :]
+                                             >= pop[:, None]]
+            return C, lambda x: (x // C0) * C + x % C0, free
+
+        split = _split_rule(new0[lo0], new0[hi0], deg, t, C0, P,
+                            free=None, sized=sized)
+        del lo0, hi0
+        C = split.N // P
+        new, old_of_new = _relabel(n, assign, P, C)
+        deg_rows = np.zeros(split.N, np.int64)
+        deg_rows[new] = deg0
+        return _assemble(split, old_of_new >= 0, old_of_new, deg_rows, P, C,
+                         t, n_logical=n)
 
 
 def _plan_from_groups(N: int, deg_logical_of_row: np.ndarray,
                       mask: np.ndarray, groups: Dict[int, List[int]],
                       threshold: int, n_logical: int) -> MirrorPlan:
     """Assemble a MirrorPlan from {primary: [rows]} (host bookkeeping)."""
-    prow = np.arange(N, dtype=np.int64)
-    for h, rows_h in groups.items():
-        prow[rows_h] = h
-    ldeg = np.where(mask, deg_logical_of_row[prow], 0)
-    primary_mask = mask & (prow == np.arange(N))
+    items = sorted(groups.items())
+    prim = np.array([h for h, rs in items for _ in rs], np.int64)
+    rows = np.array([r for _, rs in items for r in rs], np.int64)
+    return _plan_arrays(N, deg_logical_of_row, mask, prim, rows,
+                        threshold, n_logical)
 
-    n_rows = sum(len(rs) for rs in groups.values())
-    Gmax = _pow2(max(1, len(groups)))
-    Rp = _pow2(max(1, n_rows))
+
+def _plan_arrays(N: int, deg_logical_of_row: np.ndarray, mask: np.ndarray,
+                 prim: np.ndarray, rows: np.ndarray, threshold: int,
+                 n_logical: int) -> MirrorPlan:
+    """Assemble a MirrorPlan from the group rows ``rows`` and the primary
+    ``prim`` of each, grouped by ascending primary, each group's rows in
+    their given order (primary first)."""
+    prow = np.arange(N, dtype=np.int64)
+    prow[rows] = prim
+    ldeg = np.where(mask, np.asarray(deg_logical_of_row)[prow], 0)
+    primary_mask = mask & (prow == np.arange(N))
+    new_group = np.r_[True, prim[1:] != prim[:-1]] if prim.size else \
+        np.zeros(0, bool)
+    gid = np.cumsum(new_group) - 1
+    Gmax = _pow2(max(1, int(new_group.sum())))
+    Rp = _pow2(max(1, len(rows)))
     grp_rows = np.zeros(Rp, np.int64)
     grp_gid = np.full(Rp, Gmax, np.int64)
     row_gid = np.full(N, Gmax, np.int64)
-    i = 0
-    for gx, (h, rows_h) in enumerate(sorted(groups.items())):
-        for r in rows_h:
-            grp_rows[i] = r
-            grp_gid[i] = gx
-            row_gid[r] = gx
-            i += 1
-    Km = _pow2(int(ldeg[list(groups)].max()) if groups else 1)
+    grp_rows[:len(rows)] = rows
+    grp_gid[:len(rows)] = gid
+    row_gid[rows] = gid
+    Km = _pow2(int(ldeg[prim].max()) if prim.size else 1)
     return MirrorPlan(
         primary_row=jnp.asarray(prow, jnp.int32),
         ldeg=jnp.asarray(ldeg, jnp.int32),
@@ -328,8 +475,7 @@ def grow_plan(plan: MirrorPlan, rekey: np.ndarray, g2: GraphBlocks
     `rekey` is the (N_old,) old-id -> new-id map grow_blocks returned and
     `g2` the grown graph.  The rekey is monotone, so group ordering and
     the canonical within-group row order survive; the rebuilt plan is the
-    relocated original with a fresh `uid` (the mirrored compiled step
-    re-keys exactly once per grow).  Host-side.
+    relocated original with a fresh `uid`.  Host-side.
     """
     groups = {int(rekey[h]): [int(rekey[r]) for r in rs]
               for h, rs in groups_of(plan).items()}
@@ -363,8 +509,8 @@ def apply_mirrored_edits(
         (slices partition the neighborhood) and splices both sides.
 
     Returns ``(g2', plan')``; the plan always carries a fresh `uid`
-    (array content changed), so mirrored SPMD runs recompile per edit
-    batch — batch edits per window, like the stream does.  Empty
+    (array content changed); a mirrored SPMD run recompiles only when
+    the plan's pow2 sizes (Gmax, Km) or the halo capacities move.  Empty
     replicas left behind by deletes are retained: they are inert under
     every merge.  Host-side preprocessing; raises under a trace.
     """

@@ -80,8 +80,8 @@ def coreness(
 
     `mirror` (a `core.hub_split.MirrorPlan` for a split `g`) routes
     through the generic `CorenessBlockProgram` under the vertex-cut
-    dataflow: per-slice h-index partials merge through count histograms,
-    so every row of a replica group carries the hub's exact coreness —
+    dataflow: the slices' counts merge per replica group and a bisection
+    finds the group's h-index (`ops.merged_hindex`), so every row of a replica group carries the hub's exact coreness —
     bit-identical at primaries to the unsplit run.
     """
     if mirror is not None:

@@ -975,11 +975,11 @@ def _mirror_merge(red, field, nbr, mirror, combine: str) -> jax.Array:
       sum    — segmented add (bit-exact for ints; float PageRank sums
                re-associate across slices — allclose, not bit-equal).
       hindex — partials do NOT compose through h values; the merge
-               recomputes per-slice count histograms (the
-               threshold-count formulation: cnt_t = #{values >= t},
-               t = 1..Km) which ADD exactly across slices, then reads
-               h = #{t : cnt_t >= t}.  Exact because a merged h-index
-               never exceeds the logical degree <= Km.
+               counts the slices' values at or above a threshold, which
+               ADD exactly across slices, and finds each group's h,
+               capped at the group's estimate (the min-H update takes
+               min(est, h) anyway), by searching the threshold
+               (`merged_hindex`).
 
     Pure device code; the scatter targets of pad entries are pushed out
     of bounds (dropped) so a pad row id can never collide with a real
@@ -998,12 +998,10 @@ def _mirror_merge(red, field, nbr, mirror, combine: str) -> jax.Array:
         out = part[gid]
     elif combine == "hindex":
         rn = nbr[rows]
-        ve = jnp.where(rn >= 0, field.astype(jnp.int32)[jnp.clip(rn, 0)], -1)
-        t = jnp.arange(1, mirror.Km + 1, dtype=jnp.int32)
-        hist = jnp.sum(ve[:, :, None] >= t[None, None, :], axis=1)
-        hist = jnp.where(live[:, None], hist, 0)
-        cnt = jnp.zeros((G + 1, mirror.Km), hist.dtype).at[gid].add(hist)
-        out = jnp.sum(cnt >= t[None, :], axis=1).astype(red.dtype)[gid]
+        f = field.astype(jnp.int32)
+        ve = jnp.where(rn >= 0, f[jnp.clip(rn, 0)], -1)
+        out = merged_hindex(ve, live, gid, f[rows], G,
+                            mirror.Km).astype(red.dtype)
     else:
         raise ValueError(
             f"combine {combine!r} has no mirror merge; count_common routes "
@@ -1012,13 +1010,60 @@ def _mirror_merge(red, field, nbr, mirror, combine: str) -> jax.Array:
     return red.at[tgt].set(jnp.where(live, out, jnp.zeros((), red.dtype)))
 
 
+def merged_hindex(vals, live, gid, cap, G: int, Km: int, total=None):
+    """h-index of each replica group's whole neighbourhood, exactly.
+
+    vals: (R, C) int32 — the neighbour values of each group entry's
+    slice (-1 on pads); live: (R,) bool — real entries; gid: (R,) int32
+    — the entry's group in [0, G) (G on pads); cap: (R,) int32 — a
+    bound on the answer, equal on all of a group's entries; Km: a bound
+    on every group's h (its logical degree's pow2 bucket).
+    h = max{t : #{values >= t} >= t} over the union of the group's
+    slices; the count at one threshold is a sum over slices, so each
+    group searches its own threshold, each round counting every slice's
+    values at or above the group's candidate and summing them per group
+    in a (G + 1,) table.
+    ``total`` merges that table across workers (a psum on the mesh;
+    nothing on one device).  No array is Km wide.
+
+    The result is min(h, cap), which is all the min-H update
+    (``min(est, h)``) needs when ``cap`` is the estimate itself (a cap
+    of Km gives h).  The first round tests the upper end of each group's
+    range, min(cap, Km), then the range is bisected; the rounds stop
+    once every group's range has closed (a scalar merged by ``total``),
+    at most ``Km.bit_length()`` of them.  A converged estimate, whose
+    neighbourhood's h is at least itself, costs one round.  Returns the
+    (R,) result of each entry's group.
+    """
+    merge = (lambda x: x) if total is None else total
+    hi = jnp.clip(cap.astype(jnp.int32), 0, Km)
+
+    def round_(c):
+        i, lo, hi, _ = c
+        mid = jnp.where(i == 0, hi, (lo + hi + 1) // 2)
+        cnt = jnp.sum(vals >= mid[:, None], axis=1, dtype=jnp.int32)
+        tab = jnp.zeros((G + 1,), jnp.int32).at[gid].add(
+            jnp.where(live, cnt, 0))
+        ok = merge(tab)[gid] >= mid
+        lo, hi = jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)
+        open_ = merge(jnp.sum(live & (lo < hi), dtype=jnp.int32))
+        return i + 1, lo, hi, open_ > 0
+
+    _, lo, _, _ = jax.lax.while_loop(
+        lambda c: c[3] & (c[0] < int(Km).bit_length()), round_,
+        (jnp.int32(0), jnp.zeros(gid.shape, jnp.int32), hi, jnp.bool_(True)))
+    return lo
+
+
 def _mirror_merged(red, field, nbr, mirror, program):
-    """Apply `_mirror_merge` per field of a (possibly multi-) program."""
-    if program.combine == "multi":
-        return tuple(
-            _mirror_merge(r, f, nbr, mirror, c)
-            for r, f, c in zip(red, field, program.combines))
-    return _mirror_merge(red, field, nbr, mirror, program.combine)
+    """Apply `_mirror_merge` per field of a (possibly multi-) program,
+    under the named scope ``mirror_merge``."""
+    with jax.named_scope("mirror_merge"):
+        if program.combine == "multi":
+            return tuple(
+                _mirror_merge(r, f, nbr, mirror, c)
+                for r, f, c in zip(red, field, program.combines))
+        return _mirror_merge(red, field, nbr, mirror, program.combine)
 
 
 @functools.partial(

@@ -56,6 +56,7 @@ import numpy as np
 
 import jax
 
+from .. import tracing
 from .mesh import WorkerMesh, make_worker_mesh
 
 
@@ -114,7 +115,11 @@ def _local_rows(
     ownm = v & (np.where(v, nbr_rows // S, -1) == r)
     rem = v & ~ownm
     out[ownm] = (nbr_rows[ownm] - r * S).astype(np.int32)
-    out[rem] = (S + np.searchsorted(uniq_r, nbr_rows[rem])).astype(np.int32)
+    remote = nbr_rows[rem]
+    if remote.size:  # halo position of each remote id, by a dense table
+        pos = np.zeros(int(uniq_r.max()) + 1, np.int32)
+        pos[uniq_r] = np.arange(S, S + len(uniq_r), dtype=np.int32)
+        out[rem] = pos[remote]
     return out
 
 
@@ -262,6 +267,7 @@ class HaloPlan:
         )
 
 
+@tracing.span("halo.build")
 def build_halo_plan(
     g, wm: WorkerMesh = None, W: int = None,
     H_min: int = 1, K_min: int = 1,
@@ -325,15 +331,15 @@ def mirror_merge_payload(plan, n_fields: int = 1) -> int:
     A mirrored run (see `core.hub_split`) adds one combine-then-broadcast
     collective per merged field per superstep: each worker folds its
     resident replica-group rows into a dense (Gmax + 1,) per-group
-    partial table (hindex: (Gmax + 1, Km) count histograms) and the
-    tables merge with a single pmin/psum over the worker axis.  That
-    table IS the wire payload — independent of how many replica rows
-    exist or where they live, which is the point: the merge cost is
-    bounded by the number of split hubs, not by hub degree.
+    partial table and the tables merge with a single pmin/psum over the
+    worker axis.  That table IS the wire payload — independent of how
+    many replica rows exist or where they live, which is the point: the
+    merge cost is bounded by the number of split hubs, not by hub degree.
 
     Returns elements per superstep for `n_fields` min/sum fields; an
-    hindex field costs `(Gmax + 1) * Km` instead, which callers account
-    for by passing the histogram width as extra fields if they need the
-    exact figure.  Counter only — no device code.
+    hindex field merges one such table per search round (at most
+    `Km.bit_length()`, `kernels.ops.merged_hindex`), which
+    callers account for by passing the rounds as extra fields if they
+    need the exact figure.  Counter only — no device code.
     """
     return (int(plan.Gmax) + 1) * int(n_fields)

@@ -45,8 +45,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
+import numpy as np
+
 from .. import tracing
-from ..kernels.ops import BlockCtx
+from ..kernels.ops import BlockCtx, merged_hindex
 from ..kernels.ref import combine_rows, hindex_rows
 from .halo import HaloPlan, build_halo_plan
 from .mesh import AXIS, WorkerMesh, make_worker_mesh
@@ -283,6 +285,38 @@ class LocalCtx(NamedTuple):
     B: int                # blocks on this worker (fold)
     Cn: int               # nodes per block
     Cd: int
+    aux: Any = ()         # this worker's shard of `SpmdProgram.operands`
+
+
+class MeshLayout(NamedTuple):
+    """What one superstep of the last mesh program run works over.
+
+    S rows a worker gathering ``cols`` columns each (``slots`` = S·cols
+    per field per superstep, the same on every worker), a halo of H
+    entries, K entries a worker pair in the all_to_all; for a hub-split
+    graph its replica ``groups``, the plan's group rows ``Rp``, the group
+    rows each worker holds at most (``Rw``, padded), the h-index bound
+    ``Km`` and the most ``rounds`` the h-index merge may take (0
+    without a split)."""
+
+    S: int
+    cols: int
+    H: int
+    K: int
+    groups: int
+    Rp: int
+    Rw: int
+    Km: int
+    rounds: int
+    slots: int
+
+
+_LAST_LAYOUT: Optional[MeshLayout] = None
+
+
+def last_mesh_layout() -> Optional[MeshLayout]:
+    """The `MeshLayout` of the last `SpmdEngine.run_spmd` (None before)."""
+    return _LAST_LAYOUT
 
 
 class SpmdExecutor:
@@ -330,8 +364,9 @@ class SpmdExecutor:
         sh = self.wm.node_sharding()
         self.node_mask = jax.device_put(jnp.asarray(g.node_mask), sh)
         self.deg = jax.device_put(jnp.asarray(g.deg, jnp.int32), sh)
-        self._nbrl = jax.device_put(
-            self.plan.nbr_local[:, :self._cols()], sh)
+        #: adjacency columns every superstep gathers (`_cols`)
+        self.cols = self._cols()
+        self._nbrl = jax.device_put(self.plan.nbr_local[:, :self.cols], sh)
         self._send = jax.device_put(self.plan.send_idx, sh)
         self._recv = jax.device_put(self.plan.recv_pos, sh)
 
@@ -394,6 +429,39 @@ class SpmdExecutor:
         self.plan = build_halo_plan(g, self.wm)
         self._refresh(g)
         self.grows += 1
+
+    def mirror_tables(self, plan):
+        """A hub-split plan's tables as mesh operands, each split over the
+        workers like the plan tables: the logical degree of every row,
+        and each worker's resident replica-group rows (local row, group
+        id), padded to a power of two with (S, Gmax).  Built once per
+        plan and mesh."""
+        key = (plan.uid, self.wm)
+        if getattr(self, "_mirror", (None,))[0] != key:
+            S, W, G = self.wm.S, self.wm.W, plan.Gmax
+            gid = np.asarray(plan.grp_gid, np.int64)
+            rows = np.asarray(plan.grp_rows, np.int64)[gid < G]
+            gid = gid[gid < G]
+            w = rows // S
+            Rw = 8
+            while Rw < np.bincount(w, minlength=W).max(initial=0):
+                Rw *= 2
+            lrow = np.full((W, Rw), S, np.int32)
+            lgid = np.full((W, Rw), G, np.int32)
+            order = np.argsort(w, kind="stable")
+            w, rows, gid = w[order], rows[order], gid[order]
+            pos = np.arange(len(w)) - np.searchsorted(w, w)
+            lrow[w, pos] = rows - w * S
+            lgid[w, pos] = gid
+            sh = self.wm.node_sharding()
+            self._mirror = (key, (
+                jax.device_put(jnp.asarray(plan.ldeg, jnp.int32), sh),
+                jax.device_put(lrow.reshape(-1), sh),
+                jax.device_put(lgid.reshape(-1), sh)))
+            #: (replica groups, plan group rows Rp, rows a worker Rw)
+            self.mirror_sizes = (int(gid.max(initial=-1)) + 1,
+                                 len(np.asarray(plan.grp_rows)), Rw)
+        return self._mirror[1]
 
     def refresh_fields(self, g) -> None:
         """Re-stage per-node fields (node_mask/deg) after a change that
@@ -534,25 +602,27 @@ class SpmdCorenessProgram(SpmdProgram):
         return mstate, None, jnp.logical_not(jnp.any(summary))
 
 
-def _mirror_merge_shard(red, nb_vals, mirror, combine: str, base, S: int):
+def _mirror_merge_shard(red, field, nb_vals, tables, G: int, Km: int,
+                        combine: str, S: int):
     """Cross-worker replica-group merge of per-slice partials (mesh form).
 
     The on-mesh twin of `kernels.ops._mirror_merge`: each worker folds
-    only the group rows resident in its shard into the (Gmax+1[, Km])
-    per-group partial table, the tables merge across workers with ONE
-    pmin/psum collective per merged field, and every worker writes the
-    merged aggregates back to its own group rows — the combine-then-
-    broadcast step of the vertex-cut dataflow, riding the same mesh as
-    the halo exchange.  hindex merges through count-histogram partials
-    off the already-halo-served `nb_vals` (so no second exchange);
-    min/sum fold the per-slice reductions directly.  Scatter targets of
-    foreign/pad entries are pushed out of bounds (dropped).
+    only its own replica-group rows (``tables``: their local rows, S on
+    pads, and group ids, G on pads; `SpmdExecutor.mirror_tables`) into a
+    (G+1,) per-group table, the tables merge across workers with a
+    pmin/psum per merged field, and every worker writes the merged
+    aggregates back to its own group rows — the combine-then-broadcast
+    step of the vertex-cut dataflow, riding the same mesh as the halo
+    exchange.  hindex finds each group's h, capped at the group's
+    estimate in ``field`` (the min-H update takes min(est, h)), off the
+    already-halo-served `nb_vals` (`merged_hindex`: one psum of a (G+1,)
+    table a round, so no second exchange, and one round once the
+    estimate has converged); min/sum fold the per-slice reductions
+    directly.  Scatter targets of pad entries are pushed out
+    of bounds (dropped).
     """
-    G = mirror.Gmax
-    rows = jnp.asarray(mirror.grp_rows, jnp.int32)
-    gid = jnp.asarray(mirror.grp_gid, jnp.int32)
-    lrow = rows - base
-    mine = (gid < G) & (lrow >= 0) & (lrow < S)
+    lrow, gid = tables
+    mine = gid < G
     li = jnp.clip(lrow, 0, S - 1)
     if combine == "min":
         fill = jnp.iinfo(red.dtype).max
@@ -564,18 +634,15 @@ def _mirror_merge_shard(red, nb_vals, mirror, combine: str, base, S: int):
         part = jnp.zeros((G + 1,), red.dtype).at[gid].add(vals)
         out = jax.lax.psum(part, AXIS)[gid]
     elif combine == "hindex":
-        ve = nb_vals[li].astype(jnp.int32)       # (Rp, Cd) halo-served
-        t = jnp.arange(1, mirror.Km + 1, dtype=jnp.int32)
-        hist = jnp.sum(ve[:, :, None] >= t[None, None, :], axis=1)
-        hist = jnp.where(mine[:, None], hist, 0)
-        cnt = jnp.zeros((G + 1, mirror.Km), hist.dtype).at[gid].add(hist)
-        cnt = jax.lax.psum(cnt, AXIS)
-        out = jnp.sum(cnt >= t[None, :], axis=1).astype(red.dtype)[gid]
+        out = merged_hindex(nb_vals[li].astype(jnp.int32), mine, gid,
+                            field[li], G, Km,
+                            total=lambda x: jax.lax.psum(x, AXIS))
+        out = out.astype(red.dtype)
     else:
         raise ValueError(
             f"combine {combine!r} has no mirror merge; count_common routes "
             "through core.hub_split.run_common_mirror")
-    tgt = jnp.where(mine, li, S)  # OOB scatter drops foreign/pad writes
+    tgt = jnp.where(mine, li, S)  # OOB scatter drops pad writes
     return red.at[tgt].set(jnp.where(mine, out, jnp.zeros((), red.dtype)))
 
 
@@ -594,15 +661,15 @@ class SpmdBlockProgram(SpmdProgram):
     `mirror` (a `core.hub_split.MirrorPlan`) arms the vertex-cut
     dataflow: the update ctx carries the worker's slice of the LOGICAL
     degrees, and `_mirror_merge_shard` folds per-slice partials per
-    replica group between combine and update.  The plan arrays are
-    closure-captured into the compiled step (shard_map constants), so
-    the plan's `uid` is part of program identity — and of the engine's
-    compiled-step cache key (see CACHE_SCHEMAS): mirrored mesh streams
-    recompile per plan rebuild, by design.
+    replica group between combine and update.  The plan's tables enter
+    the compiled step as sharded operands (`operands`,
+    `SpmdExecutor.mirror_tables`), not as constants: only the plan's
+    static sizes (Gmax, Km) are part of program identity, so a new plan
+    of the same sizes reuses the compiled step.
 
     Hash/eq delegate to the wrapped program (plus the static real-node
-    count and mirror identity), so reusing a program object reuses the
-    per-(mesh, H) compiled superstep.
+    count and the plan's static sizes), so reusing a program object
+    reuses the per-(mesh, H) compiled superstep.
     """
 
     fusable = True
@@ -615,22 +682,27 @@ class SpmdBlockProgram(SpmdProgram):
         if prog.combine == "multi":
             self.field_names = tuple(p.name for p in prog.programs)
         self.mirror = mirror
-        self.mirror_uid = None if mirror is None else mirror.uid
+        self.mirror_key = (None if mirror is None
+                           else (mirror.Gmax, mirror.Km))
 
     def __hash__(self):
-        return hash((type(self), self.prog, self.n_real, self.mirror_uid))
+        return hash((type(self), self.prog, self.n_real, self.mirror_key))
 
     def __eq__(self, other):
         return (type(other) is type(self) and other.prog == self.prog
                 and other.n_real == self.n_real
-                and other.mirror_uid == self.mirror_uid)
+                and other.mirror_key == self.mirror_key)
+
+    def operands(self, ex):
+        """The plan's tables on the executor's mesh (none unsplit)."""
+        return () if self.mirror is None else ex.mirror_tables(self.mirror)
 
     def summary_shape(self):
         """Static W2M summary shape (the per-worker changed flag).
 
         `SpmdEngine._summary_shape` uses this instead of abstract-eval:
-        the mirrored `worker_local` calls `lax.axis_index`, which only
-        exists inside shard_map — eval_shape outside the mesh would
+        the mirrored `worker_local` merges with collectives, which only
+        exist inside shard_map — eval_shape outside the mesh would
         fail, and the summary shape is a structural constant anyway.
         """
         return jax.ShapeDtypeStruct((1,), jnp.bool_)
@@ -642,9 +714,7 @@ class SpmdBlockProgram(SpmdProgram):
         deg = ctx.deg
         S = deg.shape[0]
         if self.mirror is not None:
-            base = jax.lax.axis_index(AXIS) * S
-            deg = jax.lax.dynamic_slice(
-                jnp.asarray(self.mirror.ldeg, jnp.int32), (base,), (S,))
+            deg, lrow, lgid = ctx.aux
         bctx = BlockCtx(deg=deg, node_mask=ctx.node_mask,
                         n_real=self.n_real)
         field = self.prog.halo_field(state)
@@ -661,20 +731,81 @@ class SpmdBlockProgram(SpmdProgram):
         else:
             red = combine_rows(self.prog.combine, field, nb_vals)
         if self.mirror is not None:
-            base = jax.lax.axis_index(AXIS) * S
-            if self.prog.combine == "multi":
-                red = tuple(
-                    _mirror_merge_shard(r, nb, self.mirror, c, base, S)
-                    for r, nb, c in zip(red, nb_vals, self.prog.combines))
-            else:
-                red = _mirror_merge_shard(
-                    red, nb_vals, self.mirror, self.prog.combine, base, S)
+            G, Km = self.mirror_key
+            with jax.named_scope("mirror_merge"):
+                if self.prog.combine == "multi":
+                    red = tuple(
+                        _mirror_merge_shard(r, f, nb, (lrow, lgid), G, Km,
+                                            c, S)
+                        for r, f, nb, c in zip(red, field, nb_vals,
+                                               self.prog.combines))
+                else:
+                    red = _mirror_merge_shard(
+                        red, field, nb_vals, (lrow, lgid), G, Km,
+                        self.prog.combine, S)
         new = self.prog.update(bctx, state, red)
         changed = self.prog.changed(state, new)
         return new, changed.reshape(1)  # per-worker W2M flag
 
     def master_compute(self, mstate, summary):
         return mstate, None, jnp.logical_not(jnp.any(summary))
+
+
+def fused_step(mesh, H: int, B: int, Cn: int, Cd: int, overlap: bool,
+               program: SpmdProgram):
+    """A program's whole superstep loop as ONE shard_map'd
+    `lax.while_loop` over the worker mesh.
+
+    The W2M summary becomes a real all-gather, masterCompute runs
+    replicated on every worker, and the halt flag never reaches the
+    host — the superstep count comes back as a device scalar.
+    `max_supersteps` is an operand (like `_compiled_coreness`), so
+    varying the cap never recompiles.  Arguments: the node-sharded
+    state, degree, mask and `program.operands`; the replicated master
+    state, directive and cap; the three plan tables.
+    """
+    def local(wstate, deg, mask, aux, mstate, directive, max_supersteps,
+              nbrl, send, recv):
+        ctx = LocalCtx(deg=deg, node_mask=mask, B=B, Cn=Cn, Cd=Cd, aux=aux)
+
+        def cond(c):
+            _, _, _, halt, it = c
+            return (~halt) & (it < max_supersteps)
+
+        def body(c):
+            wstate, mstate, d, _, it = c
+            field = program.halo_field(wstate)
+            nb_vals = _gather_field(
+                field, nbrl, send, recv, H, program.halo_fill, overlap,
+                program.field_names)
+            wstate2, summary = program.worker_local(ctx, wstate, nb_vals, d)
+            full = jax.lax.all_gather(summary, AXIS, axis=0, tiled=True)
+            mstate2, d2, halt = program.master_compute(mstate, full)
+            if d2 is None:  # trace-time: keep carrying the placeholder
+                d2 = d
+            return wstate2, mstate2, d2, halt, it + 1
+
+        wstate, mstate, _, _, n = jax.lax.while_loop(
+            cond, body,
+            (wstate, mstate, directive, jnp.bool_(False), jnp.int32(0)))
+        return wstate, mstate, n
+
+    return _smap(local, mesh, 4, 3, (P_(AXIS), P_(), P_()),
+                 f"spmd_fused_{program.name}")
+
+
+def _record_layout(ex: "SpmdExecutor", mirror) -> None:
+    """Set `last_mesh_layout` for a run on ``ex`` (``mirror``: its plan)."""
+    global _LAST_LAYOUT
+    S, cols = ex.wm.S, ex.cols
+    groups = Rp = Rw = Km = rounds = 0
+    if mirror is not None:
+        ex.mirror_tables(mirror)
+        groups, Rp, Rw = ex.mirror_sizes
+        Km, rounds = int(mirror.Km), int(mirror.Km).bit_length()
+    _LAST_LAYOUT = MeshLayout(S=S, cols=cols, H=int(ex.plan.H),
+                              K=int(ex.plan.K), groups=groups, Rp=Rp, Rw=Rw,
+                              Km=Km, rounds=rounds, slots=S * cols)
 
 
 class SpmdEngine:
@@ -704,76 +835,41 @@ class SpmdEngine:
         B, Cn = ex.wm.B, ex.wm.Cn
         Cd = ex._nbrl.shape[1]
         overlap = ex.overlap
-        mirror = getattr(program, "mirror_uid", None)
+        mirror = getattr(program, "mirror_key", None)
         key = (ex.wm.mesh, H, B, Cn, Cd, overlap, program, mirror)
         cached = self._step_cache.get(key)
         if cached is not None:
             return cached
 
-        def local(wstate, deg, mask, directive, nbrl, send, recv):
+        def local(wstate, deg, mask, aux, directive, nbrl, send, recv):
             field = program.halo_field(wstate)
             nb_vals = _gather_field(
                 field, nbrl, send, recv, H, program.halo_fill, overlap,
                 program.field_names)
-            ctx = LocalCtx(deg=deg, node_mask=mask, B=B, Cn=Cn, Cd=Cd)
+            ctx = LocalCtx(deg=deg, node_mask=mask, B=B, Cn=Cn, Cd=Cd,
+                           aux=aux)
             return program.worker_local(ctx, wstate, nb_vals, directive)
 
-        fn = _smap(local, ex.wm.mesh, 3, 1, (P_(AXIS), P_(AXIS)),
+        fn = _smap(local, ex.wm.mesh, 4, 1, (P_(AXIS), P_(AXIS)),
                    f"spmd_step_{program.name}")
         self._step_cache[key] = fn
         return fn
 
     def _fused_fn(self, program: SpmdProgram):
-        """Whole superstep loop as ONE shard_map'd `lax.while_loop`.
-
-        The W2M summary becomes a real all-gather, masterCompute runs
-        replicated on every worker, and the halt flag never reaches the
-        host — the superstep count comes back as a device scalar.
-        `max_supersteps` is an operand (like `_compiled_coreness`), so
-        varying the cap never recompiles.
-        """
+        """Whole superstep loop as ONE shard_map'd `lax.while_loop`
+        (`fused_step`), cached per mesh, capacities and program."""
         ex = self.ex
         H = ex.plan.H
         B, Cn = ex.wm.B, ex.wm.Cn
         Cd = ex._nbrl.shape[1]
         overlap = ex.overlap
-        mirror = getattr(program, "mirror_uid", None)
+        mirror = getattr(program, "mirror_key", None)
         key = ("fused", ex.wm.mesh, H, B, Cn, Cd, overlap, program, mirror)
         cached = self._step_cache.get(key)
-        if cached is not None:
-            return cached
-
-        def local(wstate, deg, mask, mstate, directive, max_supersteps,
-                  nbrl, send, recv):
-            ctx = LocalCtx(deg=deg, node_mask=mask, B=B, Cn=Cn, Cd=Cd)
-
-            def cond(c):
-                _, _, _, halt, it = c
-                return (~halt) & (it < max_supersteps)
-
-            def body(c):
-                wstate, mstate, d, _, it = c
-                field = program.halo_field(wstate)
-                nb_vals = _gather_field(
-                    field, nbrl, send, recv, H, program.halo_fill, overlap,
-                    program.field_names)
-                wstate2, summary = program.worker_local(
-                    ctx, wstate, nb_vals, d)
-                full = jax.lax.all_gather(summary, AXIS, axis=0, tiled=True)
-                mstate2, d2, halt = program.master_compute(mstate, full)
-                if d2 is None:  # trace-time: keep carrying the placeholder
-                    d2 = d
-                return wstate2, mstate2, d2, halt, it + 1
-
-            wstate, mstate, _, _, n = jax.lax.while_loop(
-                cond, body,
-                (wstate, mstate, directive, jnp.bool_(False), jnp.int32(0)))
-            return wstate, mstate, n
-
-        fn = _smap(local, ex.wm.mesh, 3, 3, (P_(AXIS), P_(), P_()),
-                   f"spmd_fused_{program.name}")
-        self._step_cache[key] = fn
-        return fn
+        if cached is None:
+            cached = self._step_cache[key] = fused_step(
+                ex.wm.mesh, H, B, Cn, Cd, overlap, program)
+        return cached
 
     def _summary_shape(self, program: SpmdProgram, wstate, directive):
         """Abstract-eval the gathered W2M summary (coordinator granularity:
@@ -831,11 +927,13 @@ class SpmdEngine:
         ser = 0 if self.ex.overlap else 1
         if fuse is None:
             fuse = getattr(program, "fusable", False)
+        aux = getattr(program, "operands", lambda ex: ())(self.ex)
+        _record_layout(self.ex, getattr(program, "mirror", None))
         if fuse:
             d0 = directive if directive is not None else jnp.int32(0)
             fn = self._fused_fn(program)
             wstate, mstate, n = fn(
-                wstate, self.ex.deg, self.ex.node_mask, mstate, d0,
+                wstate, self.ex.deg, self.ex.node_mask, aux, mstate, d0,
                 jnp.int32(max_supersteps), *self.ex._tables)
             # per-superstep message sizes are static: reconstruct the trace
             # in one bulk extend, metering the *initial* directive (as
@@ -855,7 +953,8 @@ class SpmdEngine:
             # metering sees the real (None) directive.
             d = directive if directive is not None else jnp.int32(0)
             wstate, summary = step(
-                wstate, self.ex.deg, self.ex.node_mask, d, *self.ex._tables)
+                wstate, self.ex.deg, self.ex.node_mask, aux, d,
+                *self.ex._tables)
             mstate, directive, halt = program.master_compute(mstate, summary)
             self.traces.append(SuperstepTrace(
                 it, modes, BladygEngine._meter(summary, directive, w2w),

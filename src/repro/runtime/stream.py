@@ -797,9 +797,9 @@ class MirrorStream:
     exact by the split==unsplit parity guarantee.  (The Theorem-1
     clamped-recompute machinery reasons in the unsplit id space; a
     candidate-bounded mirrored maintenance pass is future work, so this
-    session recomputes.  The rebuilt plan also carries a fresh `uid`,
-    so the mirrored SPMD step recompiles per edit window — stick to
-    single-device backends for fine-grained mirrored streams.)
+    session recomputes, and on `ell_spmd` each recompute builds a fresh
+    executor and halo plan — stick to single-device backends for
+    fine-grained mirrored streams.)
 
     Duck-types the slice of `StreamSession` the serving layer consumes:
     `.g`, `.core`, `.labels`, `.backend`, `.executor` (always None —
@@ -838,8 +838,8 @@ class MirrorStream:
              Cd: Optional[int] = None) -> np.ndarray:
         """Capacity escalation under the vertex cut: pad-and-rekey the
         split graph (`core.graph.grow_blocks`) and relocate the
-        `MirrorPlan` along (`core.hub_split.grow_plan` — fresh uid, so
-        the mirrored compiled step re-keys once).  Analytics recompute
+        `MirrorPlan` along (`core.hub_split.grow_plan`, a fresh uid).
+        Analytics recompute
         mirror-aware, which is exact by split==unsplit parity.  Returns
         the rekey map."""
         from ..core.hub_split import grow_plan

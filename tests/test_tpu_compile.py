@@ -7,9 +7,10 @@ topology at the widths of the roadNet-CA full-scale deployment that
 8-slot column bucket stands for a low-degree graph, the 128-lane kernels
 take a whole lane chunk, and a 256-lane row stands for a wider graph),
 plus the smoke's whole fused static pass over its hybrid adjacency (a
-4-column head, one tail bucket of 16-column rows) and one jitted
-`ell_spmd` superstep on a
-4-device mesh built from the described devices.  Nothing runs: the TPU compiler
+4-column head, one tail bucket of 16-column rows), one jitted
+`ell_spmd` superstep on a 4-device mesh built from the described
+devices, and the hub-split com-LiveJournal refresh's whole fused loop on
+that mesh at its full size.  Nothing runs: the TPU compiler
 refuses here what it would refuse on the chip (unsupported primitives,
 misaligned tiles, fast-memory overflow), at no chip time.
 
@@ -18,6 +19,8 @@ at import — so every pytest-xdist worker collects the same tests and only
 the worker that runs this file loads the TPU compiler library.
 """
 import re
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -30,6 +33,7 @@ from repro.core.algorithms import (ConnectedComponentsProgram,
                                    CorenessBlockProgram, PageRankProgram)
 from repro.core.engine import MultiProgram
 from repro.core.graph import GraphBlocks
+from repro.core.hub_split import MirrorPlan
 from repro.kernels import ops
 from repro.kernels.ell_cc import neighbor_min_ell
 from repro.kernels.ell_frontier import frontier_step_ell
@@ -42,6 +46,10 @@ from repro.kernels.kcore_hindex import hindex_counts
 from repro.kernels.ops import DENSE_AUTO_MAX
 from repro.runtime import spmd
 from repro.runtime.mesh import AXIS
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+from bench import collectives  # noqa: E402
+from bench.trace import parse_op  # noqa: E402
 
 #: roadNet-CA at scale 1.0, 8 random blocks: P * Cn rows (N once padded to
 #: the 256-row tile); Cd = max degree 12 + deg_slack 64; C8 = an 8-column
@@ -186,3 +194,54 @@ def test_static_fused_pass_compiles_and_fits(shape):
     chunk = ROW_CHUNK // T * T
     assert sorted(gathers) == sorted(
         [(True, f"{chunk},{W4}")] * 3 + [(True, f"{TAIL},{C16}")] * 3), gathers
+
+
+#: com-LiveJournal at full scale, hubs split at 16 over 4 workers: rows
+#: a block (3,997,962 nodes, 2,144,251 replicas and the padding of the
+#: fullest block, rounded up), pow2 halo, pair, group-row and group
+#: capacities at or above the stand-in's, and the h-index bound 16,384
+LJ_CN, LJ_H, LJ_K, LJ_RW, LJ_G, LJ_KM = (876_544, 1 << 23, 1 << 21, 1 << 20,
+                                        1 << 20, 1 << 14)
+
+
+def test_split_mesh_refresh_compiles_and_fits(topo):
+    """The served refresh of the hub-split graph (coreness + CC +
+    PageRank, with the replica merges) as one fused loop on a 4-chip
+    mesh, with the benchmark cell's read (``overlap=False``): it
+    compiles, moves its halo by all-to-all, merges by all-reduce (as
+    `bench/collectives.py` classes them), and fits a chip's HBM."""
+    W, C = 4, 16
+    Nn = P * LJ_CN
+    mesh = Mesh(np.asarray(topo.devices[:W]), (AXIS,))
+    sh = NamedSharding(mesh, PartitionSpec(AXIS))
+    rep = NamedSharding(mesh, PartitionSpec())
+    S = lambda s, dt, sd=sh: jax.ShapeDtypeStruct(s, dt, sharding=sd)  # noqa
+    z = jnp.zeros(1, jnp.int32)
+    plan = MirrorPlan(primary_row=z, ldeg=z, primary_mask=z, grp_rows=z,
+                      grp_gid=z, row_gid=z, Gmax=LJ_G, Km=LJ_KM,
+                      threshold=C, n_logical=3_997_962, uid=0)
+    prog = MultiProgram((CorenessBlockProgram(), ConnectedComponentsProgram(),
+                         PageRankProgram(tol=None, max_steps=30)),
+                        max_steps=30)
+    sp = spmd.SpmdBlockProgram(prog, 3_997_962, mirror=plan)
+    vec = S((Nn,), jnp.int32)
+    state = (vec, vec, (S((Nn,), jnp.float32), S((Nn,), jnp.float32)))
+    aux = (vec, S((W * LJ_RW,), jnp.int32), S((W * LJ_RW,), jnp.int32))
+    fn = spmd.fused_step(mesh, LJ_H, P // W, LJ_CN, C, False, sp)
+    compiled = fn.lower(
+        state, vec, S((Nn,), jnp.bool_), aux, None,
+        S((), jnp.int32, rep), S((), jnp.int32, rep), S((Nn, C), jnp.int32),
+        S((W, W, LJ_K), jnp.int32), S((W, W, LJ_K), jnp.int32)).compile()
+    text = compiled.as_text()
+    ops = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+           if " = " in ln]
+    found = [parse_op(op)[:2] for op in ops if collectives.is_collective(op)]
+    # the halo of three fields by all-to-all, the merges by all-reduce;
+    # named after the jax op (``all_to_all``, ``psum``, ``pmin``), so the
+    # benchmark classes them by opcode
+    assert sorted(c for _, c in found).count("all-to-all") == 3
+    assert {c for _, c in found} == {"all-to-all", "all-reduce"}
+    assert any(not name.startswith(c) for name, c in found)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES // 4
