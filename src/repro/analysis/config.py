@@ -192,7 +192,7 @@ CACHE_SCHEMAS: Dict[str, Tuple[str, ...]] = {
     "repro/runtime/spmd.py::_compiled_reach": ("mesh", "H", "overlap"),
     "repro/runtime/spmd.py::_compiled_recompute": ("mesh", "H", "overlap"),
     "repro/runtime/spmd.py::_step_cache": (
-        "mesh", "H", "B", "Cn", "Cd", "overlap", "program", "mirror"),
+        "mesh", "H", "B", "Cn", "widths", "overlap", "program", "mirror"),
 }
 
 #: approved sorted-ELL splice/sort helpers: a `nbr` write whose value

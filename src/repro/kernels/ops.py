@@ -239,21 +239,44 @@ def _fill_widths(nbr: jax.Array):
     return jnp.sum(fill[:, None] > widths, axis=0), jnp.max(fill)
 
 
+def split_widths(wider, rows: int, cols: int,
+                 head_min: int = HYBRID_MIN_COLS,
+                 tail_floor: int = 256) -> HybridSplit:
+    """The head/tail choice of a hybrid ELL gather, from row fills alone.
+
+    ``wider[k]`` counts the rows wider than 2^k; ``rows`` every row
+    gathers a head, and the tail's whole rows are ``cols`` wide.  The
+    head width W is the power of two in [head_min, cols) that gathers
+    the fewest slots, ``rows * W + tail_padded * cols``, where the tail
+    is every row wider than W, bucketed to a power of two of at least
+    ``tail_floor``.  A head narrower than ``cols`` only comes with a
+    tail, and ties keep W = cols with no tail.  Both W and the tail
+    bucket are powers of two, so streams that nudge degrees keep hitting
+    the same compiled programs.  Shared by the fused `ell` fixpoint
+    (`hybrid_split`) and the mesh superstep (`runtime.spmd`).
+    """
+    best = HybridSplit(cols, cols, 0, 0, rows * cols)
+    W = head_min
+    while W < cols:
+        tail = int(wider[W.bit_length() - 1])
+        if tail:
+            tail_p = _pow2_bucket(tail, floor=tail_floor)
+            slots = rows * W + tail_p * cols
+            if slots < best.slots:
+                best = HybridSplit(cols, W, tail, tail_p, slots)
+        W *= 2
+    return best
+
+
 def hybrid_split(nbr: jax.Array, T: int = 256) -> HybridSplit:
     """Split the fused `ell` gather's adjacency into a head and a tail.
 
     ONE host sync per call (read at the top of a fixpoint, never inside;
     it stands in for `degree_bound` there).  The full width C is the
     kernels' column bucket of the widest row, as `degree_bound` gives it
-    for left-filled rows; the head width W is the power of two in
-    [HYBRID_MIN_COLS, C] that gathers the fewest slots,
-    ``Np * W + tail_padded * C``, where the tail is every row wider than
-    W, bucketed to a power of two of at least one tile.  A head narrower
-    than C only comes with a tail, and ties keep W = C: a graph whose
-    rows share one width keeps the single (Np, C) adjacency.  Both W and
-    the tail bucket are powers of two, so maintenance streams keep
-    hitting the same compiled programs.  Under a jit trace this falls
-    back to the padded Cd and no tail, like `degree_bound`.
+    for left-filled rows; `split_widths` picks the head over the padded
+    rows, with a tail bucket of at least one tile.  Under a jit trace
+    this falls back to the padded Cd and no tail, like `degree_bound`.
     """
     N, Cd = nbr.shape
     Cdp = max(128, _pad_to(Cd, 128))
@@ -262,17 +285,7 @@ def hybrid_split(nbr: jax.Array, T: int = 256) -> HybridSplit:
         return HybridSplit(Cdp, Cdp, 0, 0, Np * Cdp)
     wider, widest = jax.device_get(_fill_widths(nbr))
     C = min(Cdp, _ell_cols(max(1, int(widest))))
-    best = HybridSplit(C, C, 0, 0, Np * C)
-    W = HYBRID_MIN_COLS
-    while W < C:
-        tail = int(wider[W.bit_length() - 1])
-        if tail:
-            tail_p = _pow2_bucket(tail, floor=T)
-            slots = Np * W + tail_p * C
-            if slots < best.slots:
-                best = HybridSplit(C, W, tail, tail_p, slots)
-        W *= 2
-    return best
+    return split_widths(wider, Np, C, tail_floor=T)
 
 
 # ---------------------------------------------------------------------------

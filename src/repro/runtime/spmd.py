@@ -48,7 +48,9 @@ from jax.sharding import PartitionSpec
 import numpy as np
 
 from .. import tracing
-from ..kernels.ops import BlockCtx, merged_hindex
+from ..kernels.ops import (ELL_MIN_COLS, MULTI_COMBINES, BlockCtx,
+                           HybridEll, HybridSplit, merged_hindex,
+                           split_widths)
 from ..kernels.ref import combine_rows, hindex_rows
 from .halo import HaloPlan, build_halo_plan
 from .mesh import AXIS, WorkerMesh, make_worker_mesh
@@ -121,14 +123,20 @@ def _exchange_gather(field, nbrl, send, recv, H, fill, overlap: bool = False):
     `overlap=True` uses the split-phase read (`_overlap_select`): the
     all_to_all is issued first and only halo slots consume it, local
     slots gather straight from `field` — same values, one fewer
-    serialized collective phase per superstep.
+    serialized collective phase per superstep.  A hybrid ``nbrl``
+    (`SpmdExecutor.hybrid_tables`) reads its head and its tail off the
+    one exchange, each with the same read, and comes back as a
+    `HybridEll` of values: (S, Wh, ...) head values, (Tw, C, ...) tail
+    values and the tail's row ids.
     """
     with jax.named_scope("halo"):
         halo = _halo_exchange(field, send[0], recv[0], H, fill)
+    read = _overlap_select if overlap else _neighbor_vals
     with jax.named_scope("gather"):
-        if overlap:
-            return _overlap_select(field, halo, nbrl)
-        return _neighbor_vals(field, halo, nbrl)
+        if isinstance(nbrl, HybridEll):
+            return HybridEll(read(field, halo, nbrl.head),
+                             read(field, halo, nbrl.tail), nbrl.rows)
+        return read(field, halo, nbrl)
 
 
 def _gather_field(field, nbrl, send, recv, H, fill, overlap: bool, names):
@@ -291,16 +299,22 @@ class LocalCtx(NamedTuple):
 class MeshLayout(NamedTuple):
     """What one superstep of the last mesh program run works over.
 
-    S rows a worker gathering ``cols`` columns each (``slots`` = S·cols
-    per field per superstep, the same on every worker), a halo of H
-    entries, K entries a worker pair in the all_to_all; for a hub-split
-    graph its replica ``groups``, the plan's group rows ``Rp``, the group
-    rows each worker holds at most (``Rw``, padded), the h-index bound
-    ``Km`` and the most ``rounds`` the h-index merge may take (0
-    without a split)."""
+    S rows a worker, each ``cols`` columns wide; every row gathers a
+    head of ``head_cols`` columns, and the rows wider than that (at most
+    ``tail_rows`` on a worker, padded to ``tail_padded``) are gathered
+    again whole (no tail: ``head_cols`` = ``cols``); ``slots`` =
+    S·head_cols + tail_padded·cols per field per superstep, the same on
+    every worker; a halo of H entries, K entries a worker pair in the
+    all_to_all; for a hub-split graph its replica ``groups``, the plan's
+    group rows ``Rp``, the group rows each worker holds at most (``Rw``,
+    padded), the h-index bound ``Km`` and the most ``rounds`` the
+    h-index merge may take (0 without a split)."""
 
     S: int
     cols: int
+    head_cols: int
+    tail_rows: int
+    tail_padded: int
     H: int
     K: int
     groups: int
@@ -364,11 +378,14 @@ class SpmdExecutor:
         sh = self.wm.node_sharding()
         self.node_mask = jax.device_put(jnp.asarray(g.node_mask), sh)
         self.deg = jax.device_put(jnp.asarray(g.deg, jnp.int32), sh)
-        #: adjacency columns every superstep gathers (`_cols`)
-        self.cols = self._cols()
+        #: how the supersteps gather the adjacency (`_split`)
+        self.split = self._split()
+        #: adjacency columns a single-width superstep gathers
+        self.cols = self.split.cols
         self._nbrl = jax.device_put(self.plan.nbr_local[:, :self.cols], sh)
         self._send = jax.device_put(self.plan.send_idx, sh)
         self._recv = jax.device_put(self.plan.recv_pos, sh)
+        self._hybrid = None  # staged on first use (`hybrid_tables`)
 
     def place(self, g):
         """`g` with its per-node arrays split over the worker mesh like the
@@ -376,19 +393,55 @@ class SpmdExecutor:
         the stream's apply path edits, not a whole copy on the first."""
         return jax.device_put(g, self.wm.node_sharding())
 
-    def _cols(self) -> int:
-        """Adjacency columns the supersteps gather: the power of two
-        (>= 8) above the widest row, at most Cd — a road network's rows
-        hold ~4 of Cd = 70 slots.  Rows are left-filled (the sorted-ELL
-        invariant), so column j holds a neighbor somewhere iff some row
-        is wider than j: one column read per bucket finds the width on
-        the host, with no device transfer."""
+    def _split(self) -> HybridSplit:
+        """How the supersteps gather the local-frame adjacency: the full
+        width C is the power of two (>= 8) above the widest row, at most
+        Cd — a road network's rows hold ~4 of Cd = 70 slots — and
+        `split_widths` picks a head over a worker's S rows and a tail of
+        its rows wider than the head, sized by the fullest worker, since
+        every worker runs the same shapes.  Rows are left-filled (the
+        sorted-ELL invariant), so a row is wider than j iff its column j
+        holds a neighbor: one column read per power of two gives each
+        worker's count of rows wider than it, on the host, with no device
+        transfer."""
         nl = self.plan.nbr_local
-        pad = nl.shape[0] // self.wm.W + self.plan.H + 1  # S + H + 1
-        cols = 8
-        while cols < nl.shape[1] and (nl[:, cols] != pad).any():
+        W, Cd = self.wm.W, nl.shape[1]
+        S = nl.shape[0] // W
+        pad = S + self.plan.H + 1
+        wider = []
+        while (1 << len(wider)) < Cd:
+            col = nl[:, 1 << len(wider)] != pad
+            wider.append(int(col.reshape(W, S).sum(axis=1).max()))
+        cols = ELL_MIN_COLS
+        while cols < Cd and wider[cols.bit_length() - 1]:
             cols *= 2
-        return min(cols, nl.shape[1])
+        return split_widths(wider, S, min(cols, Cd), head_min=ELL_MIN_COLS,
+                            tail_floor=ELL_MIN_COLS)
+
+    def hybrid_tables(self) -> HybridEll:
+        """The split's tables as mesh operands, each split over the
+        workers like the plan tables: the head (the first ``head_cols``
+        columns of every row), each worker's rows wider than the head
+        whole (``tail_padded`` rows of ``cols`` columns a worker, pads
+        holding only the PAD slot) and their local row ids (S on pads).
+        Staged on first use after each plan change."""
+        if self._hybrid is None:
+            sp, nl = self.split, self.plan.nbr_local
+            W, Tw, C = self.wm.W, sp.tail_padded, sp.cols
+            S = nl.shape[0] // W
+            wide = np.flatnonzero(nl[:, sp.head_cols] != S + self.plan.H + 1)
+            w = wide // S
+            pos = np.arange(len(wide)) - np.searchsorted(w, w)
+            rows = np.full((W, Tw), S, np.int32)
+            tail = np.full((W, Tw, C), S + self.plan.H + 1, np.int32)
+            rows[w, pos] = wide - w * S
+            tail[w, pos] = nl[wide, :C]
+            sh = self.wm.node_sharding()
+            self._hybrid = HybridEll(
+                jax.device_put(nl[:, :sp.head_cols], sh),
+                jax.device_put(tail.reshape(W * Tw, C), sh),
+                jax.device_put(rows.reshape(W * Tw), sh))
+        return self._hybrid
 
     @tracing.span("halo.update")
     def apply_updates(self, g, edits) -> None:
@@ -646,6 +699,28 @@ def _mirror_merge_shard(red, field, nb_vals, tables, G: int, Km: int,
     return red.at[tgt].set(jnp.where(mine, out, jnp.zeros((), red.dtype)))
 
 
+def _combine(combine: str, field, nb_vals):
+    """`combine_rows` over gathered values; over a hybrid read every row
+    reduces its head, then each tail row's whole-row reduction overwrites
+    it (pads name row S and are dropped), as `ops._multi_ell_hybrid`
+    does, so min, sum and hindex stay exact.  A tail row's sum adds its
+    values in column order, as a row sum over the single table does:
+    a reduce over the few tail rows may otherwise run as a tree, and the
+    rank of a row would then change in its last bits with the split
+    (the scan adds 16 columns an iteration)."""
+    if not isinstance(nb_vals, HybridEll):
+        return combine_rows(combine, field, nb_vals)
+    red = combine_rows(combine, field, nb_vals.head)
+    if combine == "sum":
+        tail = jax.lax.scan(
+            lambda acc, col: (acc + col, None),
+            jnp.zeros_like(nb_vals.tail[:, 0]), nb_vals.tail.T,
+            unroll=16)[0]
+    else:
+        tail = combine_rows(combine, field, nb_vals.tail)
+    return red.at[nb_vals.rows].set(tail, mode="drop")
+
+
 class SpmdBlockProgram(SpmdProgram):
     """Adapter: any `core.engine.BlockProgram` as an SPMD program.
 
@@ -684,6 +759,13 @@ class SpmdBlockProgram(SpmdProgram):
         self.mirror = mirror
         self.mirror_key = (None if mirror is None
                            else (mirror.Gmax, mirror.Km))
+        combines = (prog.combines if prog.combine == "multi"
+                    else (prog.combine,))
+        #: reduces a hybrid read (`SpmdExecutor.hybrid_tables`): its
+        #: combines are exact over a head overwritten by whole tail rows,
+        #: and no mirror merge reads whole gathered rows
+        self.hybrid = mirror is None and all(
+            c in MULTI_COMBINES for c in combines)
 
     def __hash__(self):
         return hash((type(self), self.prog, self.n_real, self.mirror_key))
@@ -726,10 +808,10 @@ class SpmdBlockProgram(SpmdProgram):
             for c, f, nb, name in zip(self.prog.combines, field, nb_vals,
                                       self.field_names):
                 with jax.named_scope(name):
-                    red.append(combine_rows(c, f, nb))
+                    red.append(_combine(c, f, nb))
             red = tuple(red)
         else:
-            red = combine_rows(self.prog.combine, field, nb_vals)
+            red = _combine(self.prog.combine, field, nb_vals)
         if self.mirror is not None:
             G, Km = self.mirror_key
             with jax.named_scope("mirror_merge"):
@@ -762,7 +844,9 @@ def fused_step(mesh, H: int, B: int, Cn: int, Cd: int, overlap: bool,
     `max_supersteps` is an operand (like `_compiled_coreness`), so
     varying the cap never recompiles.  Arguments: the node-sharded
     state, degree, mask and `program.operands`; the replicated master
-    state, directive and cap; the three plan tables.
+    state, directive and cap; the three plan tables, the adjacency
+    either ``Cd`` columns of every row or a hybrid read whose head is
+    ``Cd`` wide (`SpmdExecutor.hybrid_tables`).
     """
     def local(wstate, deg, mask, aux, mstate, directive, max_supersteps,
               nbrl, send, recv):
@@ -794,18 +878,22 @@ def fused_step(mesh, H: int, B: int, Cn: int, Cd: int, overlap: bool,
                  f"spmd_fused_{program.name}")
 
 
-def _record_layout(ex: "SpmdExecutor", mirror) -> None:
-    """Set `last_mesh_layout` for a run on ``ex`` (``mirror``: its plan)."""
+def _record_layout(ex: "SpmdExecutor", mirror, split: HybridSplit) -> None:
+    """Set `last_mesh_layout` for a run on ``ex`` (``mirror``: its plan;
+    ``split``: the gather the run reads, `SpmdEngine._adjacency`)."""
     global _LAST_LAYOUT
-    S, cols = ex.wm.S, ex.cols
+    S = ex.wm.S
     groups = Rp = Rw = Km = rounds = 0
     if mirror is not None:
         ex.mirror_tables(mirror)
         groups, Rp, Rw = ex.mirror_sizes
         Km, rounds = int(mirror.Km), int(mirror.Km).bit_length()
-    _LAST_LAYOUT = MeshLayout(S=S, cols=cols, H=int(ex.plan.H),
-                              K=int(ex.plan.K), groups=groups, Rp=Rp, Rw=Rw,
-                              Km=Km, rounds=rounds, slots=S * cols)
+    _LAST_LAYOUT = MeshLayout(
+        S=S, cols=split.cols, head_cols=split.head_cols,
+        tail_rows=split.tail_rows, tail_padded=split.tail_padded,
+        H=int(ex.plan.H), K=int(ex.plan.K), groups=groups, Rp=Rp, Rw=Rw,
+        Km=Km, rounds=rounds,
+        slots=S * split.head_cols + split.tail_padded * split.cols)
 
 
 class SpmdEngine:
@@ -817,9 +905,9 @@ class SpmdEngine:
     (`plan.slot_counts()`), not from declared shapes.
     """
 
-    #: compiled program steps, keyed by (mesh, H, B, Cn, Cd, program
-    #: instance) — the program is part of the key because the closure
-    #: captures it, so reusing one program object across runs (as
+    #: compiled program steps, keyed by (mesh, H, B, Cn, gather layout,
+    #: program instance) — the program is part of the key because the
+    #: closure captures it, so reusing one program object across runs (as
     #: `coreness_via_spmd` does) reuses the compiled superstep.
     _step_cache: dict = {}
 
@@ -829,14 +917,33 @@ class SpmdEngine:
         self.ex = executor if executor is not None else SpmdExecutor(g, W=W)
         self.traces = []
 
+    def _adjacency(self, program: SpmdProgram):
+        """(the adjacency operand a program's supersteps gather, the
+        split it reads): the executor's hybrid head and tail where the
+        program reduces them (`SpmdBlockProgram.hybrid`) and the split
+        found a tail, else the single table of ``cols`` columns."""
+        ex = self.ex
+        if getattr(program, "hybrid", False) and ex.split.tail_padded:
+            return ex.hybrid_tables(), ex.split
+        S, C = ex.wm.S, ex.split.cols
+        return ex._nbrl, HybridSplit(C, C, 0, 0, S * C)
+
+    def _widths(self, program: SpmdProgram) -> Tuple[int, int, int]:
+        """The gather widths a program's step reads (`_adjacency`): head,
+        tail bucket and full row, the pow2 buckets that key its compiled
+        step, so windows that nudge degrees keep hitting it."""
+        split = self._adjacency(program)[1]
+        return split.head_cols, split.tail_padded, split.cols
+
     def _step_fn(self, program: SpmdProgram):
         ex = self.ex
         H = ex.plan.H
         B, Cn = ex.wm.B, ex.wm.Cn
-        Cd = ex._nbrl.shape[1]
+        widths = self._widths(program)
+        Cd = widths[0]
         overlap = ex.overlap
         mirror = getattr(program, "mirror_key", None)
-        key = (ex.wm.mesh, H, B, Cn, Cd, overlap, program, mirror)
+        key = (ex.wm.mesh, H, B, Cn, widths, overlap, program, mirror)
         cached = self._step_cache.get(key)
         if cached is not None:
             return cached
@@ -857,18 +964,20 @@ class SpmdEngine:
 
     def _fused_fn(self, program: SpmdProgram):
         """Whole superstep loop as ONE shard_map'd `lax.while_loop`
-        (`fused_step`), cached per mesh, capacities and program."""
+        (`fused_step`), cached per mesh, capacities, gather widths
+        (`_widths`) and program."""
         ex = self.ex
         H = ex.plan.H
         B, Cn = ex.wm.B, ex.wm.Cn
-        Cd = ex._nbrl.shape[1]
+        widths = self._widths(program)
         overlap = ex.overlap
         mirror = getattr(program, "mirror_key", None)
-        key = ("fused", ex.wm.mesh, H, B, Cn, Cd, overlap, program, mirror)
+        key = ("fused", ex.wm.mesh, H, B, Cn, widths, overlap, program,
+               mirror)
         cached = self._step_cache.get(key)
         if cached is None:
             cached = self._step_cache[key] = fused_step(
-                ex.wm.mesh, H, B, Cn, Cd, overlap, program)
+                ex.wm.mesh, H, B, Cn, widths[0], overlap, program)
         return cached
 
     def _summary_shape(self, program: SpmdProgram, wstate, directive):
@@ -928,13 +1037,15 @@ class SpmdEngine:
         if fuse is None:
             fuse = getattr(program, "fusable", False)
         aux = getattr(program, "operands", lambda ex: ())(self.ex)
-        _record_layout(self.ex, getattr(program, "mirror", None))
+        adj, split = self._adjacency(program)
+        tables = (adj, self.ex._send, self.ex._recv)
+        _record_layout(self.ex, getattr(program, "mirror", None), split)
         if fuse:
             d0 = directive if directive is not None else jnp.int32(0)
             fn = self._fused_fn(program)
             wstate, mstate, n = fn(
                 wstate, self.ex.deg, self.ex.node_mask, aux, mstate, d0,
-                jnp.int32(max_supersteps), *self.ex._tables)
+                jnp.int32(max_supersteps), *tables)
             # per-superstep message sizes are static: reconstruct the trace
             # in one bulk extend, metering the *initial* directive (as
             # BladygEngine.run_jit does) and the abstract summary shape.
@@ -953,8 +1064,7 @@ class SpmdEngine:
             # metering sees the real (None) directive.
             d = directive if directive is not None else jnp.int32(0)
             wstate, summary = step(
-                wstate, self.ex.deg, self.ex.node_mask, aux, d,
-                *self.ex._tables)
+                wstate, self.ex.deg, self.ex.node_mask, aux, d, *tables)
             mstate, directive, halt = program.master_compute(mstate, summary)
             self.traces.append(SuperstepTrace(
                 it, modes, BladygEngine._meter(summary, directive, w2w),

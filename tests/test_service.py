@@ -285,6 +285,18 @@ def test_e2e_serving_mid_stream_exact_zero_rebuilds_zero_recompiles():
             srv.step(w)
 
     play(windows[:4])  # warmup: insert AND delete windows, all kinds
+    # the refresh reads a hybrid head and tail (`SpmdExecutor.split`),
+    # and the measured windows insert and delete edges at its wide rows:
+    # the split's pow2 buckets hold, so nothing retraces per window
+    split = sess.executor.split
+    assert 0 < split.head_cols < split.cols and split.tail_padded
+    lay = spmd_mod.last_mesh_layout()
+    assert (lay.head_cols, lay.tail_padded) == (split.head_cols,
+                                                split.tail_padded)
+    deg = np.asarray(sess.g.deg)
+    for op in (1, -1):
+        assert any(o == op and max(deg[u], deg[v]) > split.head_cols
+                   for w in windows[4:] for u, v, o in w), op
     traces0 = (ops.gather_trace_count(), query_trace_count(),
                spmd_mod.step_build_count())
     play(windows[4:])

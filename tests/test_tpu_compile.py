@@ -9,8 +9,9 @@ take a whole lane chunk, and a 256-lane row stands for a wider graph),
 plus the smoke's whole fused static pass over its hybrid adjacency (a
 4-column head, one tail bucket of 16-column rows), one jitted
 `ell_spmd` superstep on a 4-device mesh built from the described
-devices, and the hub-split com-LiveJournal refresh's whole fused loop on
-that mesh at its full size.  Nothing runs: the TPU compiler
+devices, the ego-Facebook served refresh's fused loop over its hybrid
+read on one described chip, and the hub-split com-LiveJournal refresh's
+whole fused loop on that mesh at its full size.  Nothing runs: the TPU compiler
 refuses here what it would refuse on the chip (unsupported primitives,
 misaligned tiles, fast-memory overflow), at no chip time.
 
@@ -194,6 +195,49 @@ def test_static_fused_pass_compiles_and_fits(shape):
     chunk = ROW_CHUNK // T * T
     assert sorted(gathers) == sorted(
         [(True, f"{chunk},{W4}")] * 3 + [(True, f"{TAIL},{C16}")] * 3), gathers
+
+
+#: the ego-Facebook served refresh on one chip: S = 4,096 rows (8 blocks
+#: of 512), the head of 128 columns every row gathers, the tail bucket
+#: of 512 rows wider than that (352 on the stand-in), gathered whole at
+#: C = 1,109 (the table's Cd); the halo of one worker is empty
+EGO_S, EGO_CN, EGO_WH, EGO_TW, EGO_C = 4096, 512, 128, 512, 1109
+
+
+def test_hybrid_mesh_refresh_compiles_and_fits(topo):
+    """The served refresh's fused coreness + CC + PageRank loop on a
+    one-chip mesh over the hybrid read (`SpmdExecutor.hybrid_tables`),
+    with the served path's split-phase read: it compiles and fits, and
+    besides the halo's send-buffer reads its only gathers are each
+    field's head and tail."""
+    mesh = Mesh(np.asarray(topo.devices[:1]), (AXIS,))
+    sh = NamedSharding(mesh, PartitionSpec(AXIS))
+    rep = NamedSharding(mesh, PartitionSpec())
+    S = lambda s, dt, sd=sh: jax.ShapeDtypeStruct(s, dt, sharding=sd)  # noqa
+    prog = MultiProgram((CorenessBlockProgram(), ConnectedComponentsProgram(),
+                         PageRankProgram(tol=None, max_steps=30)),
+                        max_steps=30)
+    sp = spmd.SpmdBlockProgram(prog, 4039)
+    assert sp.hybrid
+    vec = S((EGO_S,), jnp.int32)
+    state = (vec, vec, (S((EGO_S,), jnp.float32), S((EGO_S,), jnp.float32)))
+    adj = ops.HybridEll(S((EGO_S, EGO_WH), jnp.int32),
+                        S((EGO_TW, EGO_C), jnp.int32),
+                        S((EGO_TW,), jnp.int32))
+    fn = spmd.fused_step(mesh, 1, EGO_S // EGO_CN, EGO_CN, EGO_WH, True, sp)
+    compiled = fn.lower(
+        state, vec, S((EGO_S,), jnp.bool_), (), None, S((), jnp.int32, rep),
+        S((), jnp.int32, rep), adj, S((1, 1, 1), jnp.int32),
+        S((1, 1, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+    shapes = re.findall(r" = \w+\[([\d,]*)\]\S* gather\(", text)
+    nbr = sorted(s for s in shapes if "," in s)
+    assert nbr == sorted([f"{EGO_S},{EGO_WH}"] * 3
+                         + [f"{EGO_TW},{EGO_C}"] * 3), shapes
+    assert len(shapes) - len(nbr) <= 3  # a send buffer per field
 
 
 #: com-LiveJournal at full scale, hubs split at 16 over 4 workers: rows
